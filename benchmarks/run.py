@@ -303,7 +303,7 @@ def decode_bench():
     from repro.kernels import autotune, pick_blocks
     from repro.kernels.qmatmul import qmatmul_pallas
     from repro.kernels.qmatvec import qmatvec_pallas
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import (merge_model, make_scan_generator,
                                     make_loop_generator)
     from repro.models.common import QuantPolicy
@@ -318,8 +318,6 @@ def decode_bench():
 
     def flops_of(fn, *args):
         c = jax.jit(fn).lower(*args).compile().cost_analysis()
-        if isinstance(c, list):
-            c = c[0]
         return float(c.get("flops", 0.0))
 
     # (a) GEMV vs padded-to-128 matmul at the decode shape.  Wall clock in
@@ -372,8 +370,8 @@ def decode_bench():
             merged = merge_model(params, pol)
             prompts = np.random.default_rng(0).integers(
                 4, cfg.vocab, size=(b, prompt_len)).astype(np.int32)
-            mesh = make_cpu_mesh()
-            with mesh:
+            mesh = make_local_mesh()
+            with jax.set_mesh(mesh):
                 # build each path's jitted callables once, warm them
                 # (compile on the first call), then time the second call —
                 # so the row measures decode throughput, not trace/compile
@@ -402,7 +400,7 @@ def _engine_compare(cfg, prefix, *, slots, prompt_len, long_gen, short_gen,
     request; the continuous engine evicts each slot at its own max-len
     and refills it from the queue mid-flight.  tok/s counts USEFUL tokens
     over wall time; both paths are warmed (compiled) first, timed after."""
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import merge_model, make_scan_generator
     from repro.models.lm import LM
     from repro.serving import ContinuousEngine, make_trace, static_schedule
@@ -417,8 +415,8 @@ def _engine_compare(cfg, prefix, *, slots, prompt_len, long_gen, short_gen,
     max_len = prompt_len + long_gen
     groups = static_schedule(trace, slots)
 
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         runners = {}
 
         def run_static():
@@ -522,7 +520,7 @@ def paged_bench():
     up front, the paged engine's high-water mark is ``peak_used`` pages
     of the pool, with shared prefix pages counted once."""
     import repro.configs as C
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import merge_model
     from repro.models.lm import LM
     from repro.models.slot_state import CACHE
@@ -553,8 +551,8 @@ def paged_bench():
         jax.tree.map(one, spec, eng.cache)
         return tot[0]
 
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         def build(**kw):
             return ContinuousEngine(lm, merged, n_slots=slots,
                                     max_len=max_len, prefill_chunk=page_size,
@@ -621,7 +619,7 @@ def adapters_bench():
     acceptance number: unmerged per-slot serving must stay within 25%
     of merged-base decode tok/s while actually multiplexing tenants."""
     import repro.configs as C
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.models.lm import LM
     from repro.serving import AdapterStore, ContinuousEngine, make_trace
 
@@ -655,8 +653,8 @@ def adapters_bench():
                        adapter_ids=("alpha", "beta", None), store=store)
     useful = sum(r.max_new_tokens for r in trace)
 
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         def run(eng, with_adapters):
             eng.reset()
             for r in trace:
@@ -724,7 +722,7 @@ def slo_bench():
     import math
 
     import repro.configs as C
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import merge_model
     from repro.models.lm import LM
     from repro.runtime import FaultInjector
@@ -738,8 +736,8 @@ def slo_bench():
     lens = dict(prompt_lens=(3, 5, 8), gen_lens=(4, 8, 12))
     trace = make_trace(n_req, cfg.vocab, seed=0, **lens)
 
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         # two warm runs through the REAL threaded serve loop: the first
         # pays compilation (compiled jits are cached module-level, so
         # fresh frontends reuse them); the second measures request
@@ -810,7 +808,7 @@ def spec_bench():
     An int2 drafter row shows the acceptance/realism tradeoff at the
     paper's lowest bit width (reported, not gated)."""
     import repro.configs as C
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import merge_model
     from repro.models.lm import LM
     from repro.serving import ContinuousEngine, make_trace
@@ -827,8 +825,8 @@ def spec_bench():
     useful = sum(r.max_new_tokens for r in trace)
     max_len = prompt_len + max(gens) + k   # +k: verify headroom
 
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         def drain(eng):
             eng.reset()
             for r in trace:
@@ -931,6 +929,8 @@ def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     picks = args.only.split(",") if args.only else list(TABLES)
     print("table,name,value,derived")
     t0 = time.time()

@@ -20,7 +20,7 @@ single-adapter model — multiplexing is free of cross-tenant interference.
 import jax
 
 import repro.configs as C
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import generate_scan
 from repro.models.lm import LM
 from repro.serving import AdapterStore, ContinuousEngine, make_trace
@@ -62,8 +62,8 @@ for r in trace:
                   adapter_id=r.adapter_id)
 outputs = engine.run()
 
-mesh = make_cpu_mesh()
-with mesh:
+mesh = make_local_mesh()
+with jax.set_mesh(mesh):
     for r in trace:
         who = store.name_of(r.adapter_id)
         ref, _ = generate_scan(lm, mesh, store.merged(who),

@@ -7,7 +7,8 @@ block shapes are now resolved in three steps:
   1. cache hit  — `experiments/autotune_cache.json`, keyed on
      ``(m, k, n, bits, group_size, rank, backend)``;
   2. measure    — when enabled, time every legal candidate on the live
-     backend (interpret on CPU, Mosaic on TPU) and persist the winner;
+     backend (interpret on CPU, Mosaic on TPU) and persist the winner
+     (raises, recording nothing, when every candidate fails);
   3. heuristic  — the original static rule.
 
 Measurement is opt-in because it runs real kernels: set
@@ -95,41 +96,51 @@ def record(key: str, blocks: Tuple[int, int, int]) -> None:
 # ---------------------------------------------------------------------------
 
 
-def _divisors_of(n: int, mult: int, cap: int) -> List[int]:
-    """Multiples of ``mult`` dividing ``n``, up to ``cap``."""
-    out = []
-    d = mult
-    while d <= min(cap, n):
-        if n % d == 0:
-            out.append(d)
-        d += mult
-    return out or [mult]
+# A TPU block's last two dims must be multiples of (8, 128) or span the
+# whole array dim.  Blocks over K cut four arrays: x [m, K] (lanes),
+# packed codes [K/cpb, N], scale/zero [K/g, N] and the adapter A [K/g, r]
+# (sublanes).  So a K block that does not span K is a multiple of 128, of
+# 8 * g and of 8 * cpb; an N block that does not span N, of 128.
+
+
+def _legal_sizes(dim: int, mult: int, cap: int) -> List[int]:
+    """Block sizes along ``dim``, largest first: the multiples of ``mult``
+    that divide ``dim``, up to ``max(cap, mult)`` — or the whole ``dim``
+    when none does (a block spanning the array is always legal)."""
+    out = [d for d in range(mult, min(max(cap, mult), dim) + 1, mult)
+           if dim % d == 0]
+    return sorted(out, reverse=True) or [dim]
+
+
+def block_k_sizes(k: int, bits: int, group_size: int, cap: int) -> List[int]:
+    from repro.core.quant import codes_per_byte
+
+    mult = math.lcm(128, 8 * group_size, 8 * codes_per_byte(bits))
+    return _legal_sizes(k, mult, cap)
+
+
+def block_n_sizes(n: int, cap: int) -> List[int]:
+    return _legal_sizes(n, 128, cap)
 
 
 def candidates(m: int, k: int, n: int, bits: int, group_size: int,
                max_bk: int = 4, max_bn: int = 4) -> List[Tuple[int, int, int]]:
-    """Legal (bm, bn, bk) triples: bk a multiple of lcm(group, cpb) that
-    divides K, bn dividing N (128-aligned when possible), VMEM-bounded.
-    Bounded to the ``max_bk`` largest K blocks x ``max_bn`` largest N
-    blocks so measurement samples across BOTH axes rather than
-    exhausting bn under a single bk."""
-    from repro.core.quant import codes_per_byte
-
-    cpb = codes_per_byte(bits)
-    kmult = group_size * cpb // math.gcd(group_size, cpb)
-    bks = _divisors_of(k, kmult, 2048)
-    nmult = 128 if n % 128 == 0 else 8
-    bns = _divisors_of(n, nmult, 512)
+    """Legal (bm, bn, bk) triples under the TPU tiling rule above,
+    VMEM-bounded.  Bounded to the ``max_bk`` largest K blocks x
+    ``max_bn`` largest N blocks so measurement samples across BOTH axes
+    rather than exhausting bn under a single bk."""
+    bks = block_k_sizes(k, bits, group_size, 2048)
+    bns = block_n_sizes(n, 512)
     bm = min(128, m)
     out = []
-    for bk in sorted(bks, reverse=True)[:max_bk]:
-        for bn in sorted(bns, reverse=True)[:max_bn]:
+    for bk in bks[:max_bk]:
+        for bn in bns[:max_bn]:
             # x + unpacked w tile + f32 acc, 4B elements, keep under ~4MB
             vmem = 4 * (bm * bk + bk * bn + bm * bn)
             if vmem > 4 * 2**20:
                 continue
             out.append((bm, bn, bk))
-    return out or [(bm, bns[0], bks[0])]
+    return out or [(bm, bns[-1], bks[-1])]
 
 
 # ---------------------------------------------------------------------------
@@ -172,7 +183,7 @@ def measure_qmatmul(m: int, k: int, n: int, bits: int, group_size: int,
         a = jax.random.normal(key0, (k // group_size, rank)) * 0.1
         b = jax.random.normal(key0, (rank, n)) * 0.1
 
-    best, best_t = None, float("inf")
+    best, best_t, first_err = None, float("inf"), None
     for bm, bn, bk in candidates(m, k, n, bits, group_size):
         try:
             if m <= GEMV_MAX_M:
@@ -197,13 +208,16 @@ def measure_qmatmul(m: int, k: int, n: int, bits: int, group_size: int,
                     group_size=group_size, block_m=bm, block_n=bn,
                     block_k=bk, interpret=interpret)
             t = _time_call(fn, reps)
-        except Exception:  # illegal tiling on this backend: skip candidate
+        except Exception as e:  # refused by this backend: try the next one
+            first_err = first_err or e
             continue
         if t < best_t:
             best, best_t = (bm, bn, bk), t
-    if best is None:  # every candidate failed; fall back to heuristic
-        from .ops import heuristic_blocks
-        best = heuristic_blocks(m, k, n, bits, group_size)
+    if best is None:
+        raise RuntimeError(
+            f"autotune: every block candidate failed for m={m} k={k} n={n} "
+            f"bits={bits} g={group_size} rank={rank} on {backend}; nothing "
+            f"recorded") from first_err
     record(cache_key(m, k, n, bits, group_size, rank, backend), best)
     return best
 

@@ -21,7 +21,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.quant import QuantizedLinear, codes_per_byte
+from repro.core.quant import QuantizedLinear
 from repro.core.qalora import QALoRAParams
 
 from . import autotune
@@ -35,26 +35,13 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _largest_divisor(n: int, cap: int, mult: int) -> int:
-    """Largest d <= cap with d | n and mult | d (mult must divide n)."""
-    assert n % mult == 0, (n, mult)
-    best = mult
-    d = mult
-    while d <= min(cap, n):
-        if n % d == 0:
-            best = d
-        d += mult
-    return best
-
-
 def heuristic_blocks(m: int, k: int, n: int, bits: int, group_size: int,
                      rank: int = 0):
-    """Static VMEM-budgeted, MXU-aligned block shapes (no measurement)."""
-    cpb = codes_per_byte(bits)
-    kmult = group_size * cpb // math.gcd(group_size, cpb)
-    block_k = _largest_divisor(k, 512, kmult)
-    block_n = _largest_divisor(n, 256, 128 if n % 128 == 0 else 8)
-    block_m = min(128, m) if m % min(128, m) == 0 else min(128, m)
+    """Static VMEM-budgeted block shapes that meet the TPU tiling rule
+    (see :func:`repro.kernels.autotune.block_k_sizes`; no measurement)."""
+    block_k = autotune.block_k_sizes(k, bits, group_size, 512)[0]
+    block_n = autotune.block_n_sizes(n, 256)[0]
+    block_m = min(128, m)
     # x + unpacked w + acc must fit VMEM comfortably (<2MB at defaults)
     return block_m, block_n, block_k
 

@@ -25,7 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import codes_per_byte
 
-from .qmatmul import _dequant_block
+from .qmatmul import _dequant_block, group_pool
 
 
 def _qalora_kernel(x_ref, qw_ref, scale_ref, zero_ref, a_ref, b_ref, o_ref,
@@ -46,7 +46,7 @@ def _qalora_kernel(x_ref, qw_ref, scale_ref, zero_ref, a_ref, b_ref, o_ref,
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     # adapter: pool x over quantization groups, contract with A's K-slice
-    pooled = x.reshape(bm, bk // group_size, group_size).sum(axis=-1)
+    pooled = group_pool(x, group_size)
     lacc_ref[...] += jax.lax.dot_general(
         pooled, a_ref[...].astype(x.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)

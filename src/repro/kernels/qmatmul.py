@@ -9,9 +9,9 @@ than bf16, which is the dominant roofline term for decode / long-context.
 Grid = (M/bm, N/bn, K/bk), K innermost; partial products accumulate in an
 f32 VMEM scratch and are written out once on the last K step.
 
-Constraints (asserted in ops.py): bk % group_size == 0,
-bk % codes_per_byte == 0, and the usual 128-multiple MXU alignment for
-bm/bn/bk on real hardware.
+Constraints: bk % group_size == 0 and bk % codes_per_byte == 0 (asserted
+below), and the TPU (8, 128) block tiling that
+:func:`repro.kernels.autotune.block_k_sizes` encodes.
 """
 
 from __future__ import annotations
@@ -27,15 +27,18 @@ from repro.core.quant import codes_per_byte
 
 
 def _unpack_block(qw_blk, bits: int, bk: int):
-    """uint8 packed [bk/cpb, bn] -> codes f32-able uint8 [bk, bn].
+    """uint8 packed [bk/cpb, bn] -> int32 codes [bk, bn].
 
     Code t of byte row r sits at logical row r*cpb + t, matching
-    :func:`repro.core.quant.pack` (reshape-interleave, axis 0).
+    :func:`repro.core.quant.pack` (reshape-interleave, axis 0).  The
+    shifts run on int32: Mosaic casts uint8 to int32 and int32 to float,
+    but refuses uint8 -> float32 directly.
     """
     cpb = codes_per_byte(bits)
+    qw_blk = qw_blk.astype(jnp.int32)
     if cpb == 1:
         return qw_blk
-    mask = jnp.uint8(2**bits - 1)
+    mask = jnp.int32(2**bits - 1)
     parts = [(qw_blk >> (bits * t)) & mask for t in range(cpb)]
     stacked = jnp.stack(parts, axis=1)  # [bk/cpb, cpb, bn]
     return stacked.reshape(bk, qw_blk.shape[-1])
@@ -50,6 +53,21 @@ def _dequant_block(qw_blk, scale_blk, zero_blk, bits: int, bk: int, group_size: 
     grouped = codes.reshape(bk // g, g, bn)
     w = grouped * scale_blk.astype(jnp.float32)[:, None, :] + zero_blk.astype(jnp.float32)[:, None, :]
     return w.reshape(bk, bn).astype(dtype)
+
+
+def group_pool(x, group_size: int):
+    """Sum each run of ``group_size`` lanes: x [m, bk] -> [m, bk/g] in
+    x.dtype (the QA-LoRA adapter input).  A matmul with a 0/1 pooling
+    matrix, because Mosaic refuses the lane-splitting reshape a
+    reshape-sum would need; the sums accumulate in f32."""
+    bk = x.shape[-1]
+    shape = (bk, bk // group_size)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    lo = jax.lax.broadcasted_iota(jnp.int32, shape, 1) * group_size
+    pool = ((row >= lo) & (row < lo + group_size)).astype(x.dtype)
+    return jax.lax.dot_general(
+        x, pool, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _qmatmul_kernel(x_ref, qw_ref, scale_ref, zero_ref, o_ref, acc_ref, *,

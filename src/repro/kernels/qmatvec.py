@@ -37,7 +37,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.quant import codes_per_byte
 
-from .qmatmul import _dequant_block
+from .qmatmul import _dequant_block, group_pool
 
 # Above this M the padded-matmul path wins (MXU utilization catches up);
 # below it the GEMV grid avoids the pad-to-block_m waste entirely.
@@ -117,7 +117,7 @@ def _qalora_matvec_kernel(x_ref, qw_ref, scale_ref, zero_ref, a_ref, b_ref,
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     # adapter: pool x over quantization groups, contract with A's K-slice
-    pooled = x.reshape(m, bk // group_size, group_size).sum(axis=-1)
+    pooled = group_pool(x, group_size)
     lacc_ref[...] += jax.lax.dot_general(
         pooled, a_ref[...].astype(x.dtype), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
@@ -191,7 +191,7 @@ def _qalora_slot_matvec_kernel(ids_ref, x_ref, qw_ref, scale_ref, zero_ref,
     # per-row adapter gather: each row contracts its pooled activations
     # with ITS OWN adapter's A-slice from the bank (dynamic leading-axis
     # slice; m <= GEMV_MAX_M keeps this a tiny unrolled loop)
-    pooled = x.reshape(m, bk // group_size, group_size).sum(axis=-1)
+    pooled = group_pool(x, group_size)
     for i in range(m):
         a_i = a_ref[pl.ds(ids_ref[i], 1)][0].astype(x.dtype)  # [bk/g, r]
         lacc_ref[i:i + 1, :] += jax.lax.dot_general(

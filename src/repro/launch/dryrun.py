@@ -107,7 +107,7 @@ def run_cell(arch: str, cell_name: str, mesh_kind: str, outdir: str,
 
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=(mesh_kind == "multipod"))
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted, args = build_step(arch, cell_name, mesh, gen_len=gen_len,
                                   policy=policy, reduced=reduced)
         lowered = jitted.lower(*args)
@@ -117,8 +117,6 @@ def run_cell(arch: str, cell_name: str, mesh_kind: str, outdir: str,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):  # older jax: one dict per device program
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
 
     rec = {

@@ -79,6 +79,25 @@ def _decode_step(lm, params, cache, tok):
 _JIT_DECODE = jax.jit(_decode_step, static_argnums=0)
 
 
+# Merge check tolerance, relative to the logit scale max|logits|.  The
+# merge is exact in real arithmetic (Appendix B); the two models differ
+# only by rounding.  The merged zero points are stored in the scale dtype
+# (bf16 on the published configs, where the paper stores FP16): half an
+# ulp, 2^-9 of each zero, per (group, column).  And the adapter model adds
+# its rank-r term in the compute dtype beside the base matmul, while the
+# merged model folds it into the weights first.  Through L bf16 layers
+# both stay within a few percent of the logit scale; a merge that drops
+# or misplaces an adapter moves logits by the adapter's whole effect.
+MERGE_LOGIT_RTOL = 5e-2
+
+
+def merge_error(logits_adapter, logits_merged) -> float:
+    """max|adapter - merged| logits over the logit scale max|adapter|."""
+    a = jnp.asarray(logits_adapter, jnp.float32)
+    m = jnp.asarray(logits_merged, jnp.float32)
+    return float(jnp.max(jnp.abs(a - m)) / jnp.max(jnp.abs(a)))
+
+
 def merge_model(params, pol=None):
     """Merge every adapter into its quantized base (exact; Appendix B).
 
@@ -255,9 +274,12 @@ def main(argv=None):
                          '"*=int4,*/attn/wo=int8,lm_head=fp"')
     args = ap.parse_args(argv)
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import repro.configs as C
     from repro.core.schemes import PolicyTree
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.models.lm import LM
 
     cfg = C.reduced(args.arch) if args.reduced else C.get(args.arch)
@@ -355,8 +377,8 @@ def main(argv=None):
             "mtp" if getattr(cfg, "mtp", False) else "*=intq8")
     paging = dict(page_size=max(args.page_size, 0),
                   n_pages=args.n_pages or None)
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         if args.engine == "frontend":
             from repro.runtime.fault import FaultInjector, PreemptionGuard
             from repro.serving import ServingFrontend, slo_summary
@@ -499,9 +521,10 @@ def main(argv=None):
                 lm, mesh, merged, prompts, args.gen_len, max_len)
             path = "prefill+scan"
 
+        dev = jax.devices()[0]
         print(f"[serve] {b} requests x {gen.shape[1]} tokens in {dt:.2f}s "
               f"({b * gen.shape[1] / max(dt, 1e-9):.1f} tok/s, {path}, "
-              f"CPU interpret)")
+              f"{dev.platform} {dev.device_kind})")
         print(f"[serve] sample generation: {gen[0][:8]}")
 
         if args.verify:
@@ -510,9 +533,11 @@ def main(argv=None):
             logits_a, _ = _JIT_DECODE(lm, params, cache_a, toks[:, :1])
             cache_m = lm.init_cache(b, max_len, dtype=jnp.float32)
             logits_m, _ = _JIT_DECODE(lm, merged, cache_m, toks[:, :1])
-            err = float(jnp.max(jnp.abs(logits_a - logits_m)))
-            print(f"[serve] merge-exactness max|adapter - merged| = {err:.2e}")
-            assert err < 5e-2, "merged model diverged from adapter model"
+            err = merge_error(logits_a, logits_m)
+            print(f"[serve] merge-exactness max|adapter - merged| / "
+                  f"max|adapter| = {err:.2e} (limit {MERGE_LOGIT_RTOL:.0e})")
+            if not err < MERGE_LOGIT_RTOL:
+                raise SystemExit("merged model diverged from adapter model")
     print("[serve] done")
 
 

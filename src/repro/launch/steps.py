@@ -22,9 +22,10 @@ from repro.sharding import (param_specs, batch_spec_tree, cache_spec_tree,
                             spec_to_sharding)
 
 
-def abstract_params(lm: LM, key=None):
-    key = jax.random.PRNGKey(0) if key is None else key
-    return jax.eval_shape(lm.init, key)
+def abstract_params(lm: LM):
+    # the key is made inside eval_shape too: no array is created, so this
+    # also runs under a mesh of described (compile-only) devices
+    return jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0)))
 
 
 def abstract_train_state(lm: LM):
@@ -41,6 +42,13 @@ def train_state_specs(lm: LM, mesh: Mesh):
     ospec = {"mu": param_specs(opt["mu"], mesh),
              "nu": param_specs(opt["nu"], mesh), "step": P()}
     return tspec, fspec, ospec
+
+
+def place(mesh: Mesh, specs, tree):
+    """``device_put`` ``tree`` onto ``mesh`` by the matching spec tree
+    (e.g. ``(tspec, fspec, ospec)`` from :func:`make_train_step`): the
+    jitted steps refuse arguments committed to a different sharding."""
+    return jax.device_put(tree, spec_to_sharding(specs, mesh))
 
 
 def make_train_fn(lm: LM, opt_cfg: AdamWConfig):
@@ -144,14 +152,12 @@ def make_sync_step(mesh: Mesh, tspec):
     (local-SGD style; DESIGN.md §6). Only meaningful on multi-pod meshes."""
     if "pod" not in mesh.shape:
         return None
-    from jax.experimental.shard_map import shard_map
-
     sh = lambda t: spec_to_sharding(t, mesh)
 
     def sync(trainable):
         def inner(tr):
             return compressed_mean(tr, "pod")
-        return shard_map(inner, mesh=mesh, in_specs=(tspec,),
-                         out_specs=tspec, check_rep=False)(trainable)
+        return jax.shard_map(inner, mesh=mesh, in_specs=(tspec,),
+                             out_specs=tspec, check_vma=False)(trainable)
 
     return jax.jit(sync, in_shardings=(sh(tspec),), out_shardings=sh(tspec))

@@ -10,7 +10,8 @@ CPU-runnable with reduced configs:
       --steps 100 --seq-len 64 --global-batch 8 --ckpt-dir /tmp/ckpt
 
 On a real pod the same driver runs with the production mesh
-(--mesh pod|multipod) and the full config.
+(--mesh pod|multipod) and the full config; --mesh local (default) is a
+1x1 mesh over this host's first device — one TPU chip, or the CPU.
 """
 
 from __future__ import annotations
@@ -40,11 +41,15 @@ def main(argv=None):
                          '"*=int4,*/attn/wo=int8,lm_head=fp"')
     ap.add_argument("--ckpt-dir", default="")
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--mesh", default="cpu", choices=["cpu", "pod", "multipod"])
+    ap.add_argument("--mesh", default="local",
+                    choices=["local", "pod", "multipod"])
     ap.add_argument("--sync-every", type=int, default=0,
                     help="cross-pod int8 adapter sync cadence (multipod)")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     import dataclasses
     import repro.configs as C
@@ -54,7 +59,7 @@ def main(argv=None):
     from repro.checkpoint import CheckpointManager
     from repro.runtime import RestartableLoop, PreemptionGuard
     from repro.launch import steps as S
-    from repro.launch.mesh import make_production_mesh, make_cpu_mesh
+    from repro.launch.mesh import make_production_mesh, make_local_mesh
 
     cfg = C.reduced(args.arch) if args.reduced else C.get(args.arch)
     q = dataclasses.replace(cfg.quant.default, mode=args.mode, bits=args.bits,
@@ -65,11 +70,11 @@ def main(argv=None):
     cfg = cfg.scaled(quant=q)
     lm = LM(cfg)
 
-    mesh = (make_cpu_mesh() if args.mesh == "cpu"
+    mesh = (make_local_mesh() if args.mesh == "local"
             else make_production_mesh(multi_pod=(args.mesh == "multipod")))
 
     opt_cfg = AdamWConfig(lr=args.lr, schedule="constant")
-    with mesh:
+    with jax.set_mesh(mesh):
         params = lm.init(jax.random.PRNGKey(0))
         trainable, frozen = split_params(params)
         opt_state = adamw_init(trainable)
@@ -93,6 +98,8 @@ def main(argv=None):
             print(f"[train] resumed from step {start}")
         if ckpt:
             ckpt.save_base(frozen)
+        trainable, frozen, opt_state = S.place(
+            mesh, (tspec, fspec, ospec), (trainable, frozen, opt_state))
 
         sync = (S.make_sync_step(mesh, tspec)
                 if args.sync_every and "pod" in mesh.shape else None)

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import (QuantPolicy, dense_view, linear_init, linear_apply,
-                     rmsnorm, rmsnorm_init, rope, constrain)
+                     rmsnorm, rmsnorm_init, rope, constrain, mesh_axis_size)
 from .scan_utils import cscan, cmap
 
 NEG_INF = -1e30
@@ -42,12 +42,6 @@ def _mask(qpos, kpos, causal: bool, window):
     return m
 
 
-def _tp_size() -> int:
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    return mesh.shape.get("model", 1) if not mesh.empty else 1
-
-
 def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
                     chunk_q=256, chunk_k=1024, scale=None, kv_len=None):
     """q: [B,Sq,H,Dq]  k: [B,Sk,KvH,Dq]  v: [B,Sk,KvH,Dv] -> [B,Sq,H,Dv].
@@ -60,7 +54,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     rows leaking into valid outputs.  Query rows >= kv_len[b] still
     attend (to the valid keys), producing garbage-but-finite output the
     caller must slice away; masked keys hit exp(NEG_INF) == 0 exactly,
-    so valid rows are bit-identical to the unpadded call.
+    so valid rows match the unpadded call up to reduction order.
 
     PERF: when the kv-head count can't shard over the model axis but the
     full head count can, the GQA [H]->[KvH,G] grouping strands the score
@@ -70,7 +64,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     """
     b, sq, h, dq = q.shape
     _, sk, kvh, _ = k.shape
-    tp = _tp_size()
+    tp = mesh_axis_size("model")
     if kvh < h and kvh % tp != 0 and h % tp == 0:
         g_exp = h // kvh
         k = jnp.repeat(k, g_exp, axis=2)

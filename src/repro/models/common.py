@@ -63,15 +63,31 @@ def rope(x, positions, theta: float = 1e4):
 # ---------------------------------------------------------------------------
 
 
+def active_mesh():
+    """The mesh entered with ``jax.set_mesh``, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
+def mesh_axis_size(*names: str) -> int:
+    """Product of the active mesh's sizes along ``names`` (absent axes and
+    no active mesh count 1)."""
+    mesh = active_mesh()
+    n = 1
+    if mesh is not None:
+        for a in names:
+            n *= mesh.shape.get(a, 1)
+    return n
+
+
 def constrain_first(x, candidates):
     """Apply the first candidate spec whose *every* named axis group exists
     on the mesh and divides its dim — unlike :func:`constrain`, which drops
     non-dividing axes per-dim, this treats each candidate atomically (used
     where fallbacks need to re-shard a *different* dim, e.g. MoE dispatch
     buffers: expert-dim EP if it divides, else token-dim DP)."""
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty:
+    mesh = active_mesh()
+    if mesh is None:
         return x
     for spec in candidates:
         spec_t = tuple(spec)
@@ -99,10 +115,8 @@ def constrain_first(x, candidates):
 def constrain(x, spec):
     """with_sharding_constraint that is a no-op outside a mesh context."""
     from jax.sharding import PartitionSpec as P
-    from jax.interpreters import pxla
-    env = pxla.thread_resources.env
-    mesh = env.physical_mesh
-    if mesh.empty or spec is None:
+    mesh = active_mesh()
+    if mesh is None or spec is None:
         return x
     # right-align the spec against x's rank
     spec = tuple(spec)
@@ -123,5 +137,4 @@ def constrain(x, spec):
             size *= mesh.shape[n]
         ok = group and x.shape[dim] % size == 0
         axes.append((group if len(group) > 1 else group[0]) if ok else None)
-    return jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(mesh, P(*axes)))
+    return jax.lax.with_sharding_constraint(x, P(*axes))

@@ -185,6 +185,14 @@ def _sp(x, cfg=None):
         return x
     return constrain(x, (("pod", "data"), "model", None))
 
+def _layer_map(fn, keys):
+    """Initialize a stack of layers one key at a time.  ``lax.map`` holds
+    a single layer's f32 weight draw; ``vmap`` would draw every layer's
+    at once (32 x [4608, 18432] f32 = 10.9 GB for one starcoder2-7b MLP
+    matrix — more than a 16 GB chip holds next to the model)."""
+    return jax.lax.map(fn, keys)
+
+
 def _maybe_remat(fn, cfg: ArchConfig):
     return jax.checkpoint(fn) if cfg.remat else fn
 
@@ -217,18 +225,18 @@ class LM:
         if fam in ("gqa", "gqa_moe"):
             moe = fam == "gqa_moe"
             bpol = pol.at("blocks")
-            params["blocks"] = jax.vmap(
-                lambda k: _gqa_block_init(k, cfg, bpol, moe))(
-                    jax.random.split(ks[2], cfg.n_layers))
+            params["blocks"] = _layer_map(
+                lambda k: _gqa_block_init(k, cfg, bpol, moe),
+                jax.random.split(ks[2], cfg.n_layers))
         elif fam == "mla_moe":
             nd = cfg.n_dense_layers
             dpol, mpol = pol.at("dense_blocks"), pol.at("moe_blocks")
-            params["dense_blocks"] = jax.vmap(
-                lambda k: _mla_block_init(k, cfg, dpol, False))(
-                    jax.random.split(ks[2], nd))
-            params["moe_blocks"] = jax.vmap(
-                lambda k: _mla_block_init(k, cfg, mpol, True))(
-                    jax.random.split(ks[3], cfg.n_layers - nd))
+            params["dense_blocks"] = _layer_map(
+                lambda k: _mla_block_init(k, cfg, dpol, False),
+                jax.random.split(ks[2], nd))
+            params["moe_blocks"] = _layer_map(
+                lambda k: _mla_block_init(k, cfg, mpol, True),
+                jax.random.split(ks[3], cfg.n_layers - nd))
             if cfg.mtp:
                 params["mtp_proj"] = linear_init(ks[4], 2 * d, d,
                                                  pol.at("mtp_proj"),
@@ -240,11 +248,11 @@ class LM:
             n_groups, per, tail = self._hybrid_layout()
             mcfg = _mamba_cfg(cfg)
             gpol, tpol = pol.at("mamba_groups"), pol.at("mamba_tail")
-            params["mamba_groups"] = jax.vmap(jax.vmap(
-                lambda k: mamba2_init(k, mcfg, gpol)))(
-                    jax.random.split(ks[2], n_groups * per).reshape(n_groups, per, 2))
-            params["mamba_tail"] = jax.vmap(
-                lambda k: mamba2_init(k, mcfg, tpol))(jax.random.split(ks[3], tail))
+            params["mamba_groups"] = _layer_map(
+                lambda kg: _layer_map(lambda k: mamba2_init(k, mcfg, gpol), kg),
+                jax.random.split(ks[2], n_groups * per).reshape(n_groups, per, 2))
+            params["mamba_tail"] = _layer_map(
+                lambda k: mamba2_init(k, mcfg, tpol), jax.random.split(ks[3], tail))
             params["shared_attn"] = _gqa_block_init(ks[4], cfg,
                                                     pol.at("shared_attn"), False)
         elif fam == "rwkv":
@@ -254,15 +262,15 @@ class LM:
                 k1, k2 = jax.random.split(k)
                 return {"ln1": rmsnorm_init(d), "ln2": rmsnorm_init(d),
                         "mix": rwkv6_init(k1, rcfg, bpol.at("mix"))}
-            params["blocks"] = jax.vmap(blk)(jax.random.split(ks[2], cfg.n_layers))
+            params["blocks"] = _layer_map(blk, jax.random.split(ks[2], cfg.n_layers))
         elif fam == "encdec":
             epol, dpol = pol.at("enc_blocks"), pol.at("dec_blocks")
-            params["enc_blocks"] = jax.vmap(
-                lambda k: self._enc_block_init(k, epol))(
-                    jax.random.split(ks[2], cfg.n_enc_layers))
-            params["dec_blocks"] = jax.vmap(
-                lambda k: self._dec_block_init(k, dpol))(
-                    jax.random.split(ks[3], cfg.n_layers))
+            params["enc_blocks"] = _layer_map(
+                lambda k: self._enc_block_init(k, epol),
+                jax.random.split(ks[2], cfg.n_enc_layers))
+            params["dec_blocks"] = _layer_map(
+                lambda k: self._dec_block_init(k, dpol),
+                jax.random.split(ks[3], cfg.n_layers))
             params["enc_ln"] = rmsnorm_init(d)
         else:
             raise ValueError(fam)
@@ -464,8 +472,9 @@ class LM:
         """``src_len`` ([B] traced int32, optional): valid frame count per
         row.  The encoder is bidirectional, so zero-padded frames WOULD
         leak into every valid output — masking keys >= src_len[b] keeps
-        valid rows bit-identical to the unpadded call (padded output rows
-        are garbage-but-finite; callers slice them away).  This is what
+        valid rows equal to the unpadded call up to reduction order
+        (padded output rows are garbage-but-finite; callers slice them
+        away).  This is what
         lets the serving engine bucket source lengths to a bounded set of
         compiled shapes."""
         cfg, pol = self.cfg, self.cfg.quant
@@ -515,8 +524,8 @@ class LM:
         ``src_len`` ([B] traced int32, optional) marks the valid frames
         of a zero-padded ``src``: rows >= src_len[b] are masked out of
         the (bidirectional) encoder attention, so valid memory rows —
-        and the cross K/V derived from them — are bit-identical to
-        encoding the unpadded source.  Callers pin only the first
+        and the cross K/V derived from them — match encoding the
+        unpadded source up to reduction order.  Callers pin only the first
         src_len rows (padded rows carry garbage-but-finite K/V)."""
         cfg, pol = self.cfg, self.cfg.quant
         memory = self._encode(params, src, src_len)

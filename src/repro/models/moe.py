@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from .common import (QuantPolicy, linear_init, linear_apply, act_fn,
-                     constrain_first)
+                     constrain_first, mesh_axis_size)
 from .scan_utils import cscan
 
 # dispatch-buffer sharding candidates [E, C, d]: full-mesh EP when the
@@ -118,17 +118,6 @@ def _expert_ffn(p, buf, pol: QuantPolicy, act: str):
     return jax.vmap(one)(p["gate"], p["up"], p["down"], buf)
 
 
-def _full_mesh_size() -> int:
-    from jax.interpreters import pxla
-    mesh = pxla.thread_resources.env.physical_mesh
-    if mesh.empty:
-        return 1
-    n = 1
-    for a in ("pod", "data", "model"):
-        n *= mesh.shape.get(a, 1)
-    return n
-
-
 def moe_apply(p, x, pol: QuantPolicy, *, n_experts: int, top_k: int,
               capacity_factor: float = 1.25, routing: str = "softmax",
               act: str = "silu", moe_chunk: int = 0):
@@ -174,7 +163,7 @@ def _moe_tokens(p, x, pol, n_experts, top_k, capacity_factor, routing, act):
     # 20x collective cut on deepseek-v3).  In the TP-fallback regime this
     # resharding HURTS (measured on mixtral: useful 0.74 -> 0.20), so it is
     # gated on divisibility.  EXPERIMENTS.md §Perf records both runs.
-    ep = n_experts % _full_mesh_size() == 0
+    ep = n_experts % mesh_axis_size("pod", "data", "model") == 0
     if ep:
         x2p = constrain_first(x2p, [s[1:] for s in _COMBINE_SPECS])
     buf = x2p[slot_tok]  # [E, C, d] gather

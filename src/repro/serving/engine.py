@@ -698,9 +698,10 @@ class ContinuousEngine:
         the next power of two (capped at ``max_src``) and the true length
         rides into the encoder as a traced ``src_len`` key mask, so at
         most O(log max_src) encoder programs ever compile under live
-        traffic with arbitrary lengths — and, because masked keys hit
-        exp(NEG_INF) == 0 exactly, the pinned rows are bit-identical to
-        encoding the unpadded source.  Only the first ``ss`` rows (and
+        traffic with arbitrary lengths.  Masked keys hit exp(NEG_INF) ==
+        0 exactly, so the pinned rows match encoding the unpadded source
+        to a few ulps (not bit-identical: XLA reduces over the padded
+        axis in another order).  Only the first ``ss`` rows (and
         the true length) are pinned; padded rows' garbage K/V never
         enters the cache.  Src-less requests keep the zeroed cross cache
         (cross len 0: a zero context, like the static token-only path)."""

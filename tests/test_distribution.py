@@ -30,15 +30,15 @@ def test_int8_compressed_cross_pod_sync():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.optim import compressed_mean
+        from repro.launch.mesh import auto_mesh
 
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = auto_mesh((2, 4), ("pod", "data"))
         x = jnp.arange(8, dtype=jnp.float32).reshape(2, 4)  # per-pod values
         def f(xs):
             return compressed_mean(xs, "pod")
-        y = shard_map(f, mesh=mesh, in_specs=P("pod", "data"),
-                      out_specs=P("pod", "data"), check_rep=False)(x)
+        y = jax.shard_map(f, mesh=mesh, in_specs=P("pod", "data"),
+                          out_specs=P("pod", "data"), check_vma=False)(x)
         expect = jnp.broadcast_to(x.mean(0, keepdims=True), x.shape)
         err = float(jnp.max(jnp.abs(y - expect)))
         assert err < 0.05, err
@@ -57,7 +57,7 @@ def test_train_step_multi_device_loss_matches_single():
         from repro.models import LM
         from repro.optim import adamw_init, split_params, AdamWConfig
         from repro.launch import steps as S
-        from repro.launch.mesh import make_cpu_mesh
+        from repro.launch.mesh import make_local_mesh
 
         cfg = C.reduced("gemma3-1b")
         lm = LM(cfg)
@@ -68,12 +68,14 @@ def test_train_step_multi_device_loss_matches_single():
         }
         ref, _ = jax.jit(lm.loss)(params, batch)
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
-        with mesh:
+        mesh = make_local_mesh(2, 4)
+        with jax.set_mesh(mesh):
             trainable, frozen = split_params(params)
             opt = adamw_init(trainable)
-            jit_for, _ = S.make_train_step(lm, mesh, AdamWConfig(lr=1e-3),
-                                           donate=False)
+            jit_for, specs = S.make_train_step(lm, mesh, AdamWConfig(lr=1e-3),
+                                               donate=False)
+            trainable, frozen, opt = S.place(mesh, specs,
+                                             (trainable, frozen, opt))
             jitted, _ = jit_for(jax.tree.map(
                 lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch))
             _, _, metrics = jitted(trainable, frozen, opt, batch)
@@ -92,10 +94,11 @@ def test_elastic_checkpoint_reshard():
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.checkpoint import save_pytree, load_pytree
+        from repro.launch.mesh import auto_mesh
 
         d = tempfile.mkdtemp()
-        m4 = jax.sharding.Mesh(np.array(jax.devices()[:4]).reshape(4), ("model",))
-        m8 = jax.make_mesh((8,), ("model",))
+        m4 = auto_mesh((4,), ("model",), devices=jax.devices()[:4])
+        m8 = auto_mesh((8,), ("model",))
         x4 = jax.device_put(jnp.arange(64, dtype=jnp.float32).reshape(8, 8),
                             NamedSharding(m4, P("model", None)))
         save_pytree({"w": x4}, d + "/ck")

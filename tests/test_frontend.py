@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 import repro.configs as C
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import merge_model
 from repro.models.lm import LM
 from repro.runtime import PreemptionGuard
@@ -32,7 +32,7 @@ def served():
 
 @pytest.fixture(scope="module")
 def mesh():
-    return make_cpu_mesh()
+    return make_local_mesh()
 
 
 class FakeClock:
@@ -69,7 +69,7 @@ def test_drained_tokens_match_raw_engine(served, mesh):
     cfg, lm, merged = served
     trace = make_trace(5, cfg.vocab, seed=2, prompt_lens=(3, 5),
                        gen_lens=(2, 6))
-    with mesh:
+    with jax.set_mesh(mesh):
         eng = ContinuousEngine(lm, merged, n_slots=2, max_len=20,
                                prefill_chunk=4, decode_burst=2)
         for r in trace:
@@ -93,7 +93,7 @@ def test_drained_tokens_match_raw_engine(served, mesh):
 
 def test_result_blocks_until_terminal(served, mesh):
     cfg, lm, merged = served
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served)
         t = fe.submit(np.array([5, 6, 7], np.int32), 3)
         assert t.status is RequestStatus.QUEUED
@@ -113,7 +113,7 @@ def test_backpressure_rejects_loudly_with_queue_depth(served, mesh):
     never silently queue past queue_cap — and the accepted requests must
     still finish."""
     cfg, lm, merged = served
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, queue_cap=3)
         ts = [fe.submit(np.array([4 + i, 9], np.int32), 2) for i in range(6)]
         assert [t.status is RequestStatus.REJECTED for t in ts] \
@@ -128,7 +128,7 @@ def test_backpressure_rejects_loudly_with_queue_depth(served, mesh):
 
 def test_invalid_requests_reject_not_raise(served, mesh):
     cfg, lm, merged = served
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, max_len=10)
         empty = fe.submit(np.array([], np.int32), 4)
         zero = fe.submit(np.array([5], np.int32), 0)
@@ -154,7 +154,7 @@ def test_total_deadline_evicts_in_flight_slot(served, mesh):
     slot still serves the deadline-free request to completion."""
     cfg, lm, merged = served
     clk = FakeClock()
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, n_slots=1, clock=clk)
         doomed = fe.submit(np.array([5, 6, 7], np.int32), 12, deadline_s=5.0)
         free = fe.submit(np.array([8, 9], np.int32), 3)
@@ -176,7 +176,7 @@ def test_ttft_deadline_times_out_queued_request(served, mesh):
     times out while queued, before ever reaching a slot."""
     cfg, lm, merged = served
     clk = FakeClock()
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, clock=clk,
                        default_ttft_deadline_s=1.0)
         stale = fe.submit(np.array([5, 6], np.int32), 4)
@@ -205,7 +205,7 @@ def test_deadline_defaults_apply_per_request_override(served):
 
 def test_cancel_queued_and_in_flight(served, mesh):
     cfg, lm, merged = served
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, n_slots=1)
         running = fe.submit(np.array([5, 6, 7], np.int32), 10)
         queued = fe.submit(np.array([8, 9], np.int32), 5)
@@ -230,7 +230,7 @@ def test_cancel_queued_and_in_flight(served, mesh):
 
 def test_stop_finishes_accepted_queue_and_rejects_new(served, mesh):
     cfg, lm, merged = served
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, n_slots=1)
         accepted = [fe.submit(np.array([5 + i, 6], np.int32), 2)
                     for i in range(3)]
@@ -249,7 +249,7 @@ def test_preemption_guard_drain_cancels_undispatched(served, mesh):
     checkpoint-and-exit contract."""
     cfg, lm, merged = served
     guard = PreemptionGuard()
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, n_slots=1, guard=guard)
         inflight = fe.submit(np.array([5, 6, 7], np.int32), 4)
         waiting = fe.submit(np.array([8, 9], np.int32), 4)
@@ -275,7 +275,7 @@ def test_threaded_live_intake_drains_clean(served, mesh):
     joins."""
     cfg, lm, merged = served
     trace = make_trace(6, cfg.vocab, seed=4, prompt_lens=(3,), gen_lens=(3,))
-    with mesh:
+    with jax.set_mesh(mesh):
         fe = _frontend(served, queue_cap=len(trace)).start()
         with pytest.raises(RuntimeError, match="already started"):
             fe.start()

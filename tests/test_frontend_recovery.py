@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 import repro.configs as C
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import merge_model
 from repro.models.lm import LM
 from repro.runtime import FaultInjector, InjectedFault
@@ -46,8 +46,8 @@ def _drain(served, *, injector=None, n_req=6, slots=2, **fe_kw):
     cfg, lm, merged = served
     trace = make_trace(n_req, cfg.vocab, seed=3,
                        prompt_lens=(3, 5, 8), gen_lens=(4, 9, 6))
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         fe = ServingFrontend(lm, merged, n_slots=slots, max_len=24,
                              prefill_chunk=4, decode_burst=2,
                              queue_cap=n_req, injector=injector, **fe_kw)
@@ -145,8 +145,8 @@ def test_recovery_smoke_single_crash(served_gqa):
     """NOT slow: one tiny request, one seeded crash — recovery happens
     and the request still finishes with the full token budget."""
     cfg, lm, merged = served_gqa
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         fe = ServingFrontend(lm, merged, n_slots=1, max_len=12,
                              prefill_chunk=4, decode_burst=2,
                              injector=FaultInjector(seed=0, crash_steps=(1,)))
@@ -163,8 +163,8 @@ def test_recovery_cap_goes_fatal_and_rejects(served_gqa):
     """Past max_recoveries the frontend fails loudly: live tickets become
     FAILED with the cause, and later submissions are REJECTED."""
     cfg, lm, merged = served_gqa
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         fe = ServingFrontend(lm, merged, n_slots=1, max_len=12,
                              prefill_chunk=4, decode_burst=2,
                              max_recoveries=2,
@@ -183,8 +183,8 @@ def test_failed_step_commits_nothing(served_gqa):
     """The invariant recovery rests on: a crashing dispatch leaves the
     scheduler's emitted streams exactly as they were."""
     cfg, lm, merged = served_gqa
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         eng = ContinuousEngine(lm, merged, n_slots=1, max_len=12,
                                prefill_chunk=4, decode_burst=2,
                                step_hook=FaultInjector(seed=0,
@@ -203,8 +203,8 @@ def test_poisoned_cache_raises_before_commit(served_gqa):
     """engine.poison_cache() -> next dispatch's in-graph health bit trips
     (EngineCorrupted) and nothing commits from that dispatch."""
     cfg, lm, merged = served_gqa
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         eng = ContinuousEngine(lm, merged, n_slots=1, max_len=12,
                                prefill_chunk=4, decode_burst=2)
         eng.submit(np.array([5, 6, 7], np.int32), 6, rid=0)

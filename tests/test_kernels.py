@@ -162,3 +162,29 @@ def test_autotune_cache_roundtrip(tmp_path, monkeypatch):
     monkeypatch.delenv(autotune.CACHE_ENV)
     autotune.clear_cache(persist=False)
     autotune._cache = None
+
+
+def test_autotune_raises_when_every_candidate_fails(tmp_path, monkeypatch):
+    """A backend that refuses every block shape is an error naming the
+    first refusal, never a heuristic recorded as if it were measured."""
+    from repro.kernels import autotune, qmatvec
+
+    path = tmp_path / "autotune_cache.json"
+    monkeypatch.setenv(autotune.CACHE_ENV, str(path))
+    autotune.clear_cache(persist=False)
+
+    def refuse(*args, **kwargs):
+        raise NotImplementedError("Unsupported cast: uint8 -> float32")
+
+    monkeypatch.setattr(qmatvec, "qmatvec_pallas", refuse)
+    m, k, n, bits, g = 1, 512, 256, 4, 32
+    try:
+        with pytest.raises(RuntimeError, match="every block candidate") as ei:
+            autotune.measure_qmatmul(m, k, n, bits, g, reps=1)
+        assert isinstance(ei.value.__cause__, NotImplementedError)
+        key = autotune.cache_key(m, k, n, bits, g, 0, jax.default_backend())
+        assert autotune.lookup(key) is None
+        assert not path.exists()
+    finally:
+        autotune.clear_cache(persist=False)
+        autotune._cache = None

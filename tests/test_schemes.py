@@ -259,7 +259,7 @@ def test_merge_tree_matches_prerefactor_and_is_idempotent():
 
 
 def test_mixed_policy_roundtrip_gemma():
-    from repro.launch.mesh import make_cpu_mesh
+    from repro.launch.mesh import make_local_mesh
     from repro.launch.serve import generate_scan, generate_loop_reference, merge_model
     from repro.optim import (AdamWConfig, adamw_init, adamw_update,
                              split_params, merge_params, count_params)
@@ -302,8 +302,8 @@ def test_mixed_policy_roundtrip_gemma():
     assert merged["head"].scheme == "fp"
 
     prompts = np.random.default_rng(0).integers(4, cfg.vocab, (2, 5)).astype(np.int32)
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         g_scan, _ = generate_scan(lm, mesh, merged, prompts, 4, 9)
         g_loop, _ = generate_loop_reference(lm, merged, prompts, 4, 9)
     np.testing.assert_array_equal(g_scan, g_loop)

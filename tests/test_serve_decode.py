@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 import repro.configs as C
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import (merge_model, generate_scan,
                                 generate_loop_reference)
 from repro.models.lm import LM
@@ -27,8 +27,8 @@ def test_scan_decode_matches_loop_gemma():
     """Greedy generations from prefill+scan == the per-token loop."""
     cfg, lm, merged, prompts = _serve_setup()
     gen_len, max_len = 4, 9
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         g_scan, _ = generate_scan(lm, mesh, merged, prompts, gen_len, max_len)
         g_loop, _ = generate_loop_reference(lm, merged, prompts, gen_len,
                                             max_len)

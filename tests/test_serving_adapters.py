@@ -18,7 +18,7 @@ import repro.configs as C
 from repro.core import QALoRAParams, dequantize, quantize
 from repro.core.qalora import adapter_delta, bank_adapter_delta
 from repro.kernels import qalora_slot_matmul
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import generate_scan, merge_model
 from repro.models.lm import LM
 from repro.serving import (AdapterStore, ContinuousEngine, RequestStatus,
@@ -63,8 +63,8 @@ def _store(raw, capacity=3, tenants=(("alpha", 0.02, 1), ("beta", 0.03, 2))):
 def _reference(lm, merged, req, max_len):
     """One request alone through the static prefill+scan path on a
     merged single-adapter tree."""
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         toks, _ = generate_scan(lm, mesh, merged, req.prompt[None, :],
                                 req.max_new_tokens, max_len)
     return [int(t) for t in toks[0]]

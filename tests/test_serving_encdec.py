@@ -194,9 +194,14 @@ def test_encode_compiles_are_bucketed_to_pow2_lengths(served_encdec):
 @pytest.mark.slow
 def test_bucketed_encode_is_bit_identical_to_unpadded(served_encdec):
     """Masked keys hit exp(NEG_INF) == 0 exactly, so the pinned cross
-    K/V from a padded+masked encode must be BIT-identical to encoding
-    the unpadded source — bucketing is a pure compile-count
-    optimization, never a numerics change."""
+    K/V from a padded+masked encode must match encoding the unpadded
+    source to within a few ulps — bucketing is a compile-count
+    optimization, never a numerics change.  Not bit-identical: XLA's CPU
+    backend reduces over the padded axis in a different order (on JAX
+    0.9, 177 of 192 elements differ by 1 ulp, max 9.5e-7), and two
+    encoder layers carry that on.  So each element may differ by 8 f32
+    epsilons of itself, plus 4 ulps of the array's largest magnitude for
+    elements near zero."""
     cfg, lm, merged = served_encdec
     for ss in (3, 5, 7):  # none on a bucket boundary
         src = _src(cfg, ss, 70 + ss)
@@ -206,5 +211,9 @@ def test_bucketed_encode_is_bit_identical_to_unpadded(served_encdec):
         pad[:ss] = src
         ks2, vs2 = jax.jit(lm.encode_cross)(
             merged, jnp.asarray(pad)[None], jnp.asarray([ss], jnp.int32))
-        np.testing.assert_array_equal(np.asarray(ks), np.asarray(ks2[:, :, :ss]))
-        np.testing.assert_array_equal(np.asarray(vs), np.asarray(vs2[:, :, :ss]))
+        for ref, got in ((ks, ks2), (vs, vs2)):
+            ref = np.asarray(ref)
+            ulp = np.spacing(np.abs(ref).max())
+            np.testing.assert_allclose(np.asarray(got[:, :, :ss]), ref,
+                                       rtol=8 * np.finfo(np.float32).eps,
+                                       atol=4 * ulp)

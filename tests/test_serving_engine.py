@@ -7,7 +7,7 @@ import numpy as np
 import pytest
 
 import repro.configs as C
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import merge_model, generate_scan
 from repro.models.lm import LM
 from repro.serving import ContinuousEngine, Request, Scheduler, make_trace
@@ -24,8 +24,8 @@ def served():
 def _reference(lm, merged, req, gen_len=None):
     """One request alone through the static prefill+scan path."""
     gen_len = req.max_new_tokens if gen_len is None else gen_len
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         toks, _ = generate_scan(lm, mesh, merged, req.prompt[None, :],
                                 gen_len, len(req.prompt) + gen_len)
     return [int(t) for t in toks[0]]

@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 import repro.configs as C
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import merge_model, generate_scan
 from repro.models.attention import (MLAConfig, mla_chunk_attention,
                                     mla_decode, mla_init, mla_init_cache,
@@ -41,8 +41,8 @@ def served_mla():
 def _reference(lm, merged, req):
     """One request alone through the static prefill+scan path."""
     gen_len = req.max_new_tokens
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         toks, _ = generate_scan(lm, mesh, merged, req.prompt[None, :],
                                 gen_len, len(req.prompt) + gen_len)
     return [int(t) for t in toks[0]]

@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 import repro.configs as C
-from repro.launch.mesh import make_cpu_mesh
+from repro.launch.mesh import make_local_mesh
 from repro.launch.serve import generate_scan, merge_model
 from repro.models.lm import LM
 from repro.serving import (AdapterStore, ContinuousEngine, Request,
@@ -42,8 +42,8 @@ def served_mla():
 
 def _reference(lm, merged, req):
     """One request alone through the static prefill+scan path."""
-    mesh = make_cpu_mesh()
-    with mesh:
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
         toks, _ = generate_scan(lm, mesh, merged, req.prompt[None, :],
                                 req.max_new_tokens,
                                 len(req.prompt) + req.max_new_tokens)

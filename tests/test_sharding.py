@@ -14,17 +14,8 @@ from repro.models import LM
 from repro.sharding import param_specs, batch_spec_tree, cache_spec_tree
 from repro.sharding.rules import _pick
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across jax versions: old API took (sizes, names),
-    newer ones take a ((name, size), ...) shape tuple."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-POD = _abstract_mesh((16, 16), ("data", "model"))
-MULTI = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+POD = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(specs, tree):

@@ -187,3 +187,29 @@ def test_preemption_guard_graceful():
         end = loop.run(body)
     assert end == 4           # stopped right after the signal
     assert saves[-1] == 4     # final save happened
+
+
+# ----------------------------- compile cache -------------------------------
+
+
+def test_compile_cache_follows_env_var(monkeypatch, tmp_path):
+    """A directory set from outside is used as is; no other is set in code."""
+    from repro.runtime import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir(monkeypatch):
+    from repro.runtime import compile_cache
+    monkeypatch.delenv(compile_cache.ENV_VAR, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert compile_cache.enable_compile_cache() == path  # fixed path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
