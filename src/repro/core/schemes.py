@@ -59,6 +59,7 @@ from . import lora as lora_lib
 from . import nf4 as nf4_lib
 from . import qalora as qalora_lib
 from . import quant as quant_lib
+from ..runtime import tracing
 
 __all__ = [
     "QuantPolicy", "FP", "PolicyTree", "resolve_policy", "resolve_path",
@@ -738,7 +739,8 @@ def linear_apply(p, x, pol=None):
     """Tag-driven forward.  ``pol`` is only consulted for legacy bare-dict
     params; tagged params carry their own resolved policy."""
     lp = _as_linear(p, pol)
-    return get_scheme(lp.scheme).apply(lp.data, x, lp.policy)
+    with tracing.scope(tracing.LINEAR):
+        return get_scheme(lp.scheme).apply(lp.data, x, lp.policy)
 
 
 def merge_linear(p, pol=None) -> LinearParams:

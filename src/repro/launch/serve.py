@@ -435,7 +435,10 @@ def main(argv=None):
                   if args.speculate else "")
             print(f"[serve] frontend: {counts} "
                   f"({fe.n_recoveries} recoveries, occupancy "
-                  f"{est.occupancy:.0%}, {est.dispatches} dispatches{sp})")
+                  f"{est.occupancy:.0%}, {est.dispatches} dispatches{sp}, "
+                  f"{est.compiles} compiles, host "
+                  f"{1e3 * est.host_s / max(est.dispatches, 1):.2f} "
+                  f"ms/dispatch)")
             print(f"[serve] SLO: ttft p50/p95 "
                   f"{s['ttft_p50_s'] * 1e3:.0f}/{s['ttft_p95_s'] * 1e3:.0f}ms"
                   f", tpot p50 {s['tpot_p50_s'] * 1e3:.1f}ms, goodput "
@@ -509,9 +512,11 @@ def main(argv=None):
                         f"{pt.peak_used}/{pt.capacity} peak, "
                         f"{pt.reused_tokens_total} prefix tokens reused, "
                         f"{pt.alloc_backoffs} backoffs")
-            dt, path = st.seconds, (f"continuous, {slots} slots, "
-                                    f"occupancy {st.occupancy:.0%}, "
-                                    f"{st.dispatches} dispatches{mix}")
+            dt, path = st.seconds, (
+                f"continuous, {slots} slots, occupancy {st.occupancy:.0%}, "
+                f"{st.dispatches} dispatches, {st.compiles} compiles, host "
+                f"{1e3 * st.host_s / max(st.dispatches, 1):.2f} ms/dispatch"
+                f"{mix}")
         elif use_loop:
             gen, dt = generate_loop_reference(
                 lm, merged, prompts, args.gen_len, max_len)

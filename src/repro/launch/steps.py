@@ -16,6 +16,7 @@ import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.lm import LM
+from repro.runtime import tracing
 from repro.optim import (AdamWConfig, adamw_init, adamw_update, split_params,
                          merge_params, compressed_mean)
 from repro.sharding import (param_specs, batch_spec_tree, cache_spec_tree,
@@ -60,7 +61,9 @@ def make_train_fn(lm: LM, opt_cfg: AdamWConfig):
 
         (loss, metrics), grads = jax.value_and_grad(
             loss_fn, has_aux=True)(trainable)
-        new_tr, new_opt, om = adamw_update(opt_cfg, grads, opt_state, trainable)
+        with tracing.scope(tracing.OPTIMIZER):
+            new_tr, new_opt, om = adamw_update(opt_cfg, grads, opt_state,
+                                               trainable)
         return new_tr, new_opt, {"loss": loss, **metrics, **om}
 
     return train_step

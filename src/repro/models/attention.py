@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from .common import (QuantPolicy, dense_view, linear_init, linear_apply,
                      rmsnorm, rmsnorm_init, rope, constrain, mesh_axis_size)
 from .scan_utils import cscan, cmap
+from ..runtime import tracing
 
 NEG_INF = -1e30
 
@@ -62,6 +63,13 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
     train_4k — EXPERIMENTS.md §Perf).  Expanding KV to H heads costs one
     O(B*S*H*hd) broadcast but lets every score/context tensor shard.
     """
+    with tracing.scope(tracing.ATTENTION):
+        return _flash(q, k, v, causal, window, q_offset, chunk_q, chunk_k,
+                      scale, kv_len)
+
+
+def _flash(q, k, v, causal, window, q_offset, chunk_q, chunk_k, scale,
+           kv_len):
     b, sq, h, dq = q.shape
     _, sk, kvh, _ = k.shape
     tp = mesh_axis_size("model")
@@ -132,21 +140,22 @@ def chunk_attention(q, k_cache, v_cache, qpos, *, window=None):
     exactly, so results are independent of the cache capacity S and of
     whatever stale KV a previous slot occupant left beyond qpos.
     """
-    b, c, h, dq = q.shape
-    _, s, kvh, _ = k_cache.shape
-    g = h // kvh
-    qg = q.reshape(b, c, kvh, g, dq)
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) / math.sqrt(dq)
-    kpos = jnp.arange(s)
-    valid = kpos[None, None, :] <= qpos[:, :, None]  # [B, C, S]
-    if window is not None:
-        active = window > 0
-        valid &= (kpos[None, None, :] > (qpos[:, :, None] - window)) | ~active
-    scores = jnp.where(valid[:, None, None, :, :], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v_cache.astype(jnp.float32))
-    return out.reshape(b, c, h, -1).astype(q.dtype)
+    with tracing.scope(tracing.ATTENTION):
+        b, c, h, dq = q.shape
+        _, s, kvh, _ = k_cache.shape
+        g = h // kvh
+        qg = q.reshape(b, c, kvh, g, dq)
+        scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg.astype(jnp.float32),
+                            k_cache.astype(jnp.float32)) / math.sqrt(dq)
+        kpos = jnp.arange(s)
+        valid = kpos[None, None, :] <= qpos[:, :, None]  # [B, C, S]
+        if window is not None:
+            active = window > 0
+            valid &= (kpos[None, None, :] > (qpos[:, :, None] - window)) | ~active
+        scores = jnp.where(valid[:, None, None, :, :], scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhgqk,bkhd->bqhgd", p, v_cache.astype(jnp.float32))
+        return out.reshape(b, c, h, -1).astype(q.dtype)
 
 
 def decode_attention(q, k_cache, v_cache, cur_len, *, window=None):
@@ -298,9 +307,10 @@ def _insert_tokens_paged(pool, new, cur_len, n_new, pages):
 def _cache_insert(cache_leaf, new, cur_len, n_new, pages):
     """Insert dispatch: contiguous slotted leaf when pages is None, paged
     pool otherwise."""
-    if pages is None:
-        return _insert_tokens(cache_leaf, new, cur_len, n_new)
-    return _insert_tokens_paged(cache_leaf, new, cur_len, n_new, pages)
+    with tracing.scope(tracing.KV_INSERT):
+        if pages is None:
+            return _insert_tokens(cache_leaf, new, cur_len, n_new)
+        return _insert_tokens_paged(cache_leaf, new, cur_len, n_new, pages)
 
 
 def _cache_view(cache_leaf, pages):

@@ -38,6 +38,7 @@ same scan-vs-loop caveat, PR 1); treat MoE serving as approximate.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import weakref
@@ -48,7 +49,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import schemes
-from ..runtime import compile_guard
+from ..runtime import compile_guard, tracing
 from .paging import PageTable, pages_for
 from .scheduler import Request, Scheduler
 from .speculative import accept_drafts, rollback_counts
@@ -172,6 +173,14 @@ _JIT_DRAFT = jax.jit(_draft_steps, static_argnums=0,
                      static_argnames=("k_steps",))
 _JIT_VERIFY = jax.jit(_verify_step, static_argnums=0)
 _JIT_SPEC_MTP = jax.jit(_spec_step_mtp, static_argnums=0)
+_JITS = (_JIT_STEP, _JIT_BURST, _JIT_RESET, _JIT_ENCODE, _JIT_DRAFT,
+         _JIT_VERIFY, _JIT_SPEC_MTP)
+
+
+def _cached_programs() -> int:
+    """Executables the engine's jits hold (CompileGuard's probe); grows
+    by one for every compile or persistent-cache load."""
+    return sum(f._cache_size() for f in _JITS)
 
 
 def make_self_drafter(params, policy: str, base=None, key=None):
@@ -220,7 +229,17 @@ class EngineStats:
     drafts offered to a verify step, and of those the longest-prefix
     matches that actually committed (the per-dispatch bonus/correction
     token is a plain greedy token, counted in ``tokens_out`` but never
-    in ``accepted_tokens``)."""
+    in ``accepted_tokens``).
+
+    ``seconds`` = ``host_s`` + ``sync_s``: ``sync_s`` is the time the host
+    waits for the device's results (the ``repro.engine.sync`` span),
+    ``host_s`` the rest of each iteration (admission, plan, the dispatch
+    call, commit, and the frontend's intake and harvest).
+    ``forward_passes`` counts target-model passes (a burst of k is k, a
+    ragged or verify dispatch 1); ``compiles`` the executables the
+    engine's module-level jits added during its iterations (a compile or
+    a persistent-cache load; the count is process-wide, so another
+    engine compiling on another thread at the same time adds to it)."""
 
     model_steps: int = 0      # model rows computed per slot (C per dispatch)
     dispatches: int = 0       # host->device program launches
@@ -230,6 +249,10 @@ class EngineStats:
     seconds: float = 0.0
     proposed_tokens: int = 0  # draft tokens offered to a verify step
     accepted_tokens: int = 0  # of those, committed (prefix-matched)
+    host_s: float = 0.0       # host seconds outside the sync span
+    sync_s: float = 0.0       # seconds waiting for device results
+    forward_passes: int = 0   # target-model passes
+    compiles: int = 0         # new executables of the engine's jits
 
     @property
     def occupancy(self) -> float:
@@ -608,11 +631,16 @@ class ContinuousEngine:
         ``tok_per_s`` is meaningful for any driver — including an
         external per-step loop like ``ServingFrontend`` — and even for a
         step that dies mid-dispatch."""
-        t0 = time.time()
+        st = self.stats
+        sync0, n0 = st.sync_s, _cached_programs()
+        t0 = time.perf_counter()
         try:
             self._step_once_inner()
         finally:
-            self.stats.seconds += time.time() - t0
+            dt = time.perf_counter() - t0
+            st.seconds += dt
+            st.host_s += dt - (st.sync_s - sync0)
+            st.compiles += _cached_programs() - n0
         guard = compile_guard.current()
         if guard is not None:
             # after the step, not inside the finally: a budget violation
@@ -622,6 +650,17 @@ class ContinuousEngine:
     def _step_once_inner(self):
         if self.step_hook is not None:
             self.step_hook(self)
+        with tracing.span(tracing.ADMIT):
+            self._admit()
+        if self.sched.all_decoding:
+            if self.speculate:
+                self._run_spec()
+            else:
+                self._run_burst()
+        else:
+            self._run_ragged()
+
+    def _admit(self):
         filled = self.sched.admit()
         if filled:
             # evict + refill, family-agnostic: one batched SlotState.reset
@@ -640,13 +679,31 @@ class ContinuousEngine:
             self._publish_pages(filled)
             self._pin_cross(filled)
         self._refresh_adapters()
-        if self.sched.all_decoding:
-            if self.speculate:
-                self._run_spec()
-            else:
-                self._run_burst()
-        else:
-            self._run_ragged()
+
+    @contextlib.contextmanager
+    def _sync(self):
+        """The host waits for the device here and nowhere else: the
+        ``repro.engine.sync`` span, timed into ``stats.sync_s``."""
+        t0 = time.perf_counter()
+        try:
+            with tracing.span(tracing.SYNC):
+                yield
+        finally:
+            self.stats.sync_s += time.perf_counter() - t0
+
+    @contextlib.contextmanager
+    def host_part(self, name: str):
+        """Host work a caller does around :meth:`step_once` for this
+        engine (the frontend's intake and harvest): the span ``name``,
+        timed into ``stats.host_s`` and ``stats.seconds``."""
+        t0 = time.perf_counter()
+        try:
+            with tracing.span(name):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            self.stats.host_s += dt
+            self.stats.seconds += dt
 
     def _publish_pages(self, filled):
         """Paged admission: write each refilled slot's page row — and seed
@@ -730,67 +787,83 @@ class ContinuousEngine:
 
     def _run_ragged(self):
         """One mixed prefill/decode ragged step."""
-        tokens, n_new = self.sched.plan()
-        nxt, ok, self.cache = _JIT_STEP(self.lm, self.params, self.aux,
-                                        self.cache, jnp.asarray(tokens),
-                                        jnp.asarray(n_new))
-        if self.draft_cache is not None:
-            # self-speculation ride-along: the drafter consumes the SAME
-            # plan so its cache rows stay in lockstep with the target's
-            # (prompt chunks and plain decode tokens alike); its output
-            # tokens are discarded, its health deliberately unchecked
-            # (garbage drafts are rejected by verification, never
-            # committed).  Same chunk-width program family as the
-            # target's step, keyed by the drafter's own params treedef.
-            _, _, self.draft_cache = _JIT_STEP(
-                self.lm, self.draft_params, self.draft_aux,
-                self.draft_cache, jnp.asarray(tokens), jnp.asarray(n_new))
-            self.stats.dispatches += 1
-        if not bool(ok):
-            raise EngineCorrupted(
-                "non-finite logits in ragged step (decode state is "
-                "poisoned); tokens NOT committed")
-        nxt = np.asarray(nxt)
-        # slots past their prompt after this plan emit one token each;
-        # mid-prompt slots consume rows but emit nothing yet
-        emitting = sum(1 for i, s in enumerate(self.sched.slots)
-                       if s is not None and n_new[i] > 0 and not s.prefilling)
-        self.sched.commit(nxt)
-        st = self.stats
-        c = int(tokens.shape[1])
-        st.dispatches += 1
-        st.model_steps += c
-        # model-step units: this dispatch computed C rows for every slot,
-        # of which each slot consumed n_new (same units as _run_burst)
-        st.slot_steps += self.n_slots * c
-        st.busy_slot_steps += int(n_new.sum())
-        st.tokens_out += emitting
+        with tracing.span(tracing.PLAN):
+            tokens, n_new = self.sched.plan()
+            tokens_d, n_new_d = jnp.asarray(tokens), jnp.asarray(n_new)
+        with tracing.span(tracing.DISPATCH):
+            nxt, ok, self.cache = _JIT_STEP(self.lm, self.params, self.aux,
+                                            self.cache, tokens_d, n_new_d)
+            if self.draft_cache is not None:
+                # self-speculation ride-along: the drafter consumes the
+                # SAME plan so its cache rows stay in lockstep with the
+                # target's (prompt chunks and plain decode tokens alike);
+                # its output tokens are discarded, its health deliberately
+                # unchecked (garbage drafts are rejected by verification,
+                # never committed).  Same chunk-width program family as
+                # the target's step, keyed by the drafter's own params
+                # treedef.
+                _, _, self.draft_cache = _JIT_STEP(
+                    self.lm, self.draft_params, self.draft_aux,
+                    self.draft_cache, tokens_d, n_new_d)
+                self.stats.dispatches += 1
+        with self._sync():
+            if not bool(ok):
+                raise EngineCorrupted(
+                    "non-finite logits in ragged step (decode state is "
+                    "poisoned); tokens NOT committed")
+            nxt = np.asarray(nxt)
+        with tracing.span(tracing.COMMIT):
+            # slots past their prompt after this plan emit one token each;
+            # mid-prompt slots consume rows but emit nothing yet
+            emitting = sum(1 for i, s in enumerate(self.sched.slots)
+                           if s is not None and n_new[i] > 0
+                           and not s.prefilling)
+            self.sched.commit(nxt)
+            st = self.stats
+            c = int(tokens.shape[1])
+            st.dispatches += 1
+            st.forward_passes += 1
+            st.model_steps += c
+            # model-step units: this dispatch computed C rows for every
+            # slot, of which each slot consumed n_new (same units as
+            # _run_burst)
+            st.slot_steps += self.n_slots * c
+            st.busy_slot_steps += int(n_new.sum())
+            st.tokens_out += emitting
 
     def _run_burst(self):
         """K fused decode steps in one program (per-slot stop masks)."""
-        tok, remaining, eos = self.sched.burst_state()
-        # follow the SHORTEST active request so finished slots are evicted
-        # and refilled promptly (occupancy), rounding DOWN to a power of
-        # two: never overshoots the shortest request, and only
-        # O(log(decode_burst)) scan programs ever compile.  An EOS-stopped
-        # slot still idles on-device until the burst returns.
-        k_min = int(remaining[remaining > 0].min())
-        k = int(min(self.decode_burst, 1 << (k_min.bit_length() - 1)))
-        self.cache, tok_d, rem_d, emitted, ok = _JIT_BURST(
-            self.lm, self.params, self.aux, self.cache, jnp.asarray(tok),
-            jnp.asarray(remaining), jnp.asarray(eos), k_steps=k)
-        if not bool(ok):
-            raise EngineCorrupted(
-                "non-finite logits in decode burst (decode state is "
-                "poisoned); tokens NOT committed")
-        emitted = np.asarray(emitted)
-        self.sched.commit_burst(emitted, np.asarray(tok_d), np.asarray(rem_d))
-        st = self.stats
-        st.dispatches += 1
-        st.model_steps += k
-        st.slot_steps += self.n_slots * k
-        st.busy_slot_steps += int((emitted >= 0).sum())
-        st.tokens_out += int((emitted >= 0).sum())
+        with tracing.span(tracing.PLAN):
+            tok, remaining, eos = self.sched.burst_state()
+            # follow the SHORTEST active request so finished slots are
+            # evicted and refilled promptly (occupancy), rounding DOWN to
+            # a power of two: never overshoots the shortest request, and
+            # only O(log(decode_burst)) scan programs ever compile.  An
+            # EOS-stopped slot still idles on-device until the burst
+            # returns.
+            k_min = int(remaining[remaining > 0].min())
+            k = int(min(self.decode_burst, 1 << (k_min.bit_length() - 1)))
+            args = (jnp.asarray(tok), jnp.asarray(remaining),
+                    jnp.asarray(eos))
+        with tracing.span(tracing.DISPATCH):
+            self.cache, tok_d, rem_d, emitted, ok = _JIT_BURST(
+                self.lm, self.params, self.aux, self.cache, *args, k_steps=k)
+        with self._sync():
+            if not bool(ok):
+                raise EngineCorrupted(
+                    "non-finite logits in decode burst (decode state is "
+                    "poisoned); tokens NOT committed")
+            emitted = np.asarray(emitted)
+            tok_d, rem_d = np.asarray(tok_d), np.asarray(rem_d)
+        with tracing.span(tracing.COMMIT):
+            self.sched.commit_burst(emitted, tok_d, rem_d)
+            st = self.stats
+            st.dispatches += 1
+            st.forward_passes += k
+            st.model_steps += k
+            st.slot_steps += self.n_slots * k
+            st.busy_slot_steps += int((emitted >= 0).sum())
+            st.tokens_out += int((emitted >= 0).sum())
 
     def _run_spec(self):
         """One draft-and-verify speculative dispatch (all slots
@@ -810,67 +883,80 @@ class ContinuousEngine:
         advanced for the failed dispatch — irrelevant, because the
         corruption contract already requires a full ``reset()`` before
         serving continues (``ServingFrontend`` rebuilds and replays)."""
-        tok, remaining, eos = self.sched.burst_state()
-        active = remaining > 0
         st = self.stats
         if self._mtp_draft:
-            held = self.sched.spec_drafts()
-            have = active & (held >= 0)
-            tokens = np.stack([tok, np.maximum(held, 0)], axis=1)
-            n_new = np.where(active, np.where(have, 2, 1), 0)
-            n_new = n_new.astype(np.int32)
-            v, mtp_d, ok, self.cache = _JIT_SPEC_MTP(
-                self.lm, self.params, self.aux, self.cache,
-                jnp.asarray(tokens), jnp.asarray(n_new))
+            with tracing.span(tracing.PLAN):
+                tok, remaining, eos = self.sched.burst_state()
+                active = remaining > 0
+                held = self.sched.spec_drafts()
+                have = active & (held >= 0)
+                tokens = np.stack([tok, np.maximum(held, 0)], axis=1)
+                n_new = np.where(active, np.where(have, 2, 1), 0)
+                n_new = n_new.astype(np.int32)
+                args = (jnp.asarray(tokens), jnp.asarray(n_new))
+            with tracing.span(tracing.DISPATCH):
+                v, mtp_d, ok, self.cache = _JIT_SPEC_MTP(
+                    self.lm, self.params, self.aux, self.cache, *args)
             st.dispatches += 1
-            if not bool(ok):
-                raise EngineCorrupted(
-                    "non-finite logits in speculative verify (decode "
-                    "state is poisoned); tokens NOT committed")
-            v, mtp_d = np.asarray(v), np.asarray(mtp_d)
+            with self._sync():
+                if not bool(ok):
+                    raise EngineCorrupted(
+                        "non-finite logits in speculative verify (decode "
+                        "state is poisoned); tokens NOT committed")
+                v, mtp_d = np.asarray(v), np.asarray(mtp_d)
             drafts = np.where(have, held, -1)[:, None]
             proposed = int(have.sum())
         else:
             k = self.speculate
-            self.draft_cache, d = _JIT_DRAFT(
-                self.lm, self.draft_params, self.draft_aux,
-                self.draft_cache, jnp.asarray(tok), jnp.asarray(active),
-                k_steps=k + 1)
-            drafts = np.asarray(d)[:k].T          # [B, k] = d_1..d_k
-            tokens = np.concatenate([tok[:, None], drafts], axis=1)
-            n_new = np.where(active, k + 1, 0).astype(np.int32)
-            v, ok, self.cache = _JIT_VERIFY(
-                self.lm, self.params, self.aux, self.cache,
-                jnp.asarray(tokens), jnp.asarray(n_new))
+            with tracing.span(tracing.PLAN):
+                tok, remaining, eos = self.sched.burst_state()
+                active = remaining > 0
+                args = (jnp.asarray(tok), jnp.asarray(active))
+            with tracing.span(tracing.DISPATCH):
+                self.draft_cache, d = _JIT_DRAFT(
+                    self.lm, self.draft_params, self.draft_aux,
+                    self.draft_cache, *args, k_steps=k + 1)
+            with self._sync():
+                drafts = np.asarray(d)[:k].T          # [B, k] = d_1..d_k
+            with tracing.span(tracing.PLAN):
+                tokens = np.concatenate([tok[:, None], drafts], axis=1)
+                n_new = np.where(active, k + 1, 0).astype(np.int32)
+                args = (jnp.asarray(tokens), jnp.asarray(n_new))
+            with tracing.span(tracing.DISPATCH):
+                v, ok, self.cache = _JIT_VERIFY(
+                    self.lm, self.params, self.aux, self.cache, *args)
             st.dispatches += 2
-            if not bool(ok):
-                raise EngineCorrupted(
-                    "non-finite logits in speculative verify (decode "
-                    "state is poisoned); tokens NOT committed")
-            v = np.asarray(v)
+            with self._sync():
+                if not bool(ok):
+                    raise EngineCorrupted(
+                        "non-finite logits in speculative verify (decode "
+                        "state is poisoned); tokens NOT committed")
+                v = np.asarray(v)
             proposed = k * int(active.sum())
-        emitted, m = accept_drafts(drafts, v, n_new, remaining, eos)
-        # un-advance the rejected tail on every cache that stepped:
-        # values-only length updates (the compiled programs never see a
-        # new shape), sound because every read mask is bounded by the
-        # slot's own len (SlotState.supports_rollback, checked at
-        # construction) — identical for contiguous and paged layouts
-        rb = rollback_counts(n_new, m)
-        dec = jnp.asarray(rb.astype(np.int32))
-        self.cache["len"] = self.cache["len"] - dec
-        if self.draft_cache is not None:
-            self.draft_cache["len"] = self.draft_cache["len"] - dec
-        if self._mtp_draft:
-            # the next-dispatch draft: column m-1 is the MTP prediction
-            # conditioned on exactly the committed stream (rows 0..m-1
-            # plus the new last token v[m-1])
-            nd = mtp_d[np.arange(self.n_slots), np.maximum(m - 1, 0)]
-            self.sched.set_spec_drafts(np.where(m > 0, nd, -1))
-        self.sched.commit_spec(emitted, m)
-        c = int(tokens.shape[1])
-        st.model_steps += c
-        st.slot_steps += self.n_slots * c
-        st.busy_slot_steps += int(n_new.sum())
-        st.tokens_out += int(m.sum())
-        st.proposed_tokens += proposed
-        st.accepted_tokens += int(np.maximum(m - 1, 0).sum())
+        with tracing.span(tracing.COMMIT):
+            emitted, m = accept_drafts(drafts, v, n_new, remaining, eos)
+            # un-advance the rejected tail on every cache that stepped:
+            # values-only length updates (the compiled programs never see
+            # a new shape), sound because every read mask is bounded by
+            # the slot's own len (SlotState.supports_rollback, checked at
+            # construction) — identical for contiguous and paged layouts
+            rb = rollback_counts(n_new, m)
+            dec = jnp.asarray(rb.astype(np.int32))
+            self.cache["len"] = self.cache["len"] - dec
+            if self.draft_cache is not None:
+                self.draft_cache["len"] = self.draft_cache["len"] - dec
+            if self._mtp_draft:
+                # the next-dispatch draft: column m-1 is the MTP
+                # prediction conditioned on exactly the committed stream
+                # (rows 0..m-1 plus the new last token v[m-1])
+                nd = mtp_d[np.arange(self.n_slots), np.maximum(m - 1, 0)]
+                self.sched.set_spec_drafts(np.where(m > 0, nd, -1))
+            self.sched.commit_spec(emitted, m)
+            c = int(tokens.shape[1])
+            st.forward_passes += 1
+            st.model_steps += c
+            st.slot_steps += self.n_slots * c
+            st.busy_slot_steps += int(n_new.sum())
+            st.tokens_out += int(m.sum())
+            st.proposed_tokens += proposed
+            st.accepted_tokens += int(np.maximum(m - 1, 0).sum())

@@ -66,6 +66,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
+from ..runtime import tracing
 from .engine import ContinuousEngine, EngineStats
 
 
@@ -92,7 +93,9 @@ class Ticket:
     step never commits, so these survive crash recovery verbatim);
     terminal non-FINISHED tickets keep whatever partial tokens existed.
     Deadlines are relative seconds from ``t_submit``; timing fields are
-    frontend-clock stamps at dispatch granularity."""
+    frontend-clock stamps at dispatch granularity (``t_admit``: the
+    reading taken just before the engine iteration whose admission gave
+    the request a slot)."""
 
     rid: int
     prompt: np.ndarray
@@ -108,6 +111,7 @@ class Ticket:
     error: str = ""
     n_recoveries: int = 0                    # engine rebuilds while live
     t_submit: float = 0.0
+    t_admit: Optional[float] = None          # iteration that gave a slot
     t_first: Optional[float] = None          # first committed token seen
     t_done: Optional[float] = None           # terminal transition
     done: threading.Event = dataclasses.field(
@@ -336,30 +340,37 @@ class ServingFrontend:
         engine dispatch (with fault recovery).  Returns True while work
         remains.  Single-driver: call either directly OR via start(),
         never both."""
-        now = self._clock()
-        if self._t0 is None:
-            self._t0 = now
-        if (self.guard is not None and self.guard.requested
-                and not self._draining):
-            # SIGTERM: stop admission, cancel the undispatched queue,
-            # finish in-flight slots (the training-loop PreemptionGuard
-            # contract, serving-shaped)
-            with self._lock:
-                self._draining = True
-                self._drain_cancel = True
-        if self._drain_cancel:
-            self._apply_drain_cancel()
-        self._process_cancels()
-        self._enforce_deadlines(now)
-        self._admit_intake()
+        with tracing.span(tracing.FRONTEND_STEP):
+            return self._step()
+
+    def _step(self) -> bool:
+        with self.engine.host_part(tracing.INTAKE):
+            now = self._clock()
+            if self._t0 is None:
+                self._t0 = now
+            if (self.guard is not None and self.guard.requested
+                    and not self._draining):
+                # SIGTERM: stop admission, cancel the undispatched queue,
+                # finish in-flight slots (the training-loop
+                # PreemptionGuard contract, serving-shaped)
+                with self._lock:
+                    self._draining = True
+                    self._drain_cancel = True
+            if self._drain_cancel:
+                self._apply_drain_cancel()
+            self._process_cancels()
+            self._enforce_deadlines(now)
+            self._admit_intake()
         worked = False
         if self.fatal is None and self.engine.sched.has_work:
+            t_iter = self._clock()
             try:
                 self.engine.step_once()
             except Exception as e:  # InjectedFault, EngineCorrupted, bugs
                 self._recover(e)
             else:
-                self._harvest(self._clock())
+                with self.engine.host_part(tracing.HARVEST):
+                    self._harvest(self._clock(), t_iter)
             worked = True
         self._t_last = self._clock()
         with self._lock:
@@ -476,9 +487,12 @@ class ServingFrontend:
                 # evicted between frontend submit and engine admission)
                 self._finish(t, RequestStatus.REJECTED, error=str(e))
 
-    def _harvest(self, now: float):
-        """Fold committed engine state into tickets: RUNNING transitions,
-        first-token stamps, FINISHED outputs."""
+    def _harvest(self, now: float, t_iter: Optional[float] = None):
+        """Fold committed engine state into tickets: RUNNING transitions
+        (stamped ``t_admit`` = ``t_iter``, the clock before the engine
+        iteration; ``now`` where not given), first-token stamps, FINISHED
+        outputs."""
+        t_iter = now if t_iter is None else t_iter
         sched = self.engine.sched
         with self._lock:
             for s in sched.slots:
@@ -487,6 +501,7 @@ class ServingFrontend:
                 t = self.tickets[s.req.rid]
                 if t.status is RequestStatus.QUEUED:
                     t.status = RequestStatus.RUNNING
+                    t.t_admit = t_iter
                 if s.emitted:
                     t.tokens = t._base + s.emitted
                     if t.t_first is None:
@@ -497,6 +512,8 @@ class ServingFrontend:
                 self._done_harvested.add(rid)
                 t = self.tickets[rid]
                 t.tokens = t._base + toks
+                if t.t_admit is None:  # admitted and done in one iteration
+                    t.t_admit = t_iter
                 if t.t_first is None:
                     t.t_first = now
                 self._finish(t, RequestStatus.FINISHED, now=now)
@@ -557,15 +574,8 @@ def _zero_stats():
 
 
 def _sum_stats(a, b):
-    return EngineStats(
-        model_steps=a.model_steps + b.model_steps,
-        dispatches=a.dispatches + b.dispatches,
-        tokens_out=a.tokens_out + b.tokens_out,
-        slot_steps=a.slot_steps + b.slot_steps,
-        busy_slot_steps=a.busy_slot_steps + b.busy_slot_steps,
-        seconds=a.seconds + b.seconds,
-        proposed_tokens=a.proposed_tokens + b.proposed_tokens,
-        accepted_tokens=a.accepted_tokens + b.accepted_tokens)
+    return EngineStats(**{f.name: getattr(a, f.name) + getattr(b, f.name)
+                          for f in dataclasses.fields(EngineStats)})
 
 
 def slo_summary(frontend: ServingFrontend) -> Dict[str, float]:

@@ -215,3 +215,26 @@ def test_poisoned_cache_raises_before_commit(served_gqa):
         with pytest.raises(EngineCorrupted):
             eng.step_once()
         assert list(eng.sched.slots[0].emitted) == before
+
+
+def test_engine_counters_survive_a_rebuild(served_gqa):
+    """``engine_stats`` sums every EngineStats field over the engines a
+    recovery discards and the live one, the counters added for tracing
+    among them."""
+    import dataclasses
+
+    cfg, lm, merged = served_gqa
+    mesh = make_local_mesh()
+    with jax.set_mesh(mesh):
+        fe = ServingFrontend(lm, merged, n_slots=1, max_len=16,
+                             prefill_chunk=4, decode_burst=2,
+                             injector=FaultInjector(seed=0, crash_steps=(3,)))
+        t = fe.submit(np.array([5, 6, 7], np.int32), 8)
+        fe.run_until_drained()
+    assert fe.n_recoveries == 1 and t.status is RequestStatus.FINISHED
+    base, live, total = fe._stats_base, fe.engine.stats, fe.engine_stats
+    assert base.forward_passes > 0 and base.dispatches > 0
+    assert base.host_s > 0 and base.sync_s > 0
+    for f in dataclasses.fields(total):
+        assert getattr(total, f.name) == pytest.approx(
+            getattr(base, f.name) + getattr(live, f.name)), f.name

@@ -125,11 +125,12 @@ WAIVERS = [
         "rule": "RL006",
         "path": "src/repro/serving/engine.py",
         "reason": (
-            "time.time() in step_once feeds only EngineStats.seconds "
-            "(tok/s reporting); token state, scheduling decisions and "
-            "recovery replay never read the clock, so determinism is "
-            "unaffected.  The frontend's deadline clock is injectable "
-            "and is the one determinism-sensitive timer."),
+            "time.perf_counter() in the engine feeds only EngineStats' "
+            "seconds, host_s and sync_s (reporting); token state, "
+            "scheduling decisions and recovery replay never read the "
+            "clock, so determinism is unaffected.  The frontend's "
+            "deadline clock is injectable and is the one "
+            "determinism-sensitive timer."),
     },
     {
         "rule": "RL004",
