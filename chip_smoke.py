@@ -9,9 +9,11 @@ published widths (all 32 layers), through the entry points a user calls.
           logits agree with the adapter model's
   serve   ``ContinuousEngine`` (4 slots, 8 requests) against the static
           ``generate_scan`` path
-  kernel  the merged model through the Pallas kernels
-          (``QuantPolicy.use_kernel``) against the XLA path, and each
-          kernel against ``kernels/ref.py`` at a full-width shape
+  kernel  the engine's decode burst holds the Pallas GEMV (merged INT-N
+          linears take it on TPU at decode rows, so the serve phase's
+          engine tokens, checked against XLA's teacher-forced argmax, ran
+          through it), and each kernel against ``kernels/ref.py`` at a
+          full-width shape
 
 Run from the repository root:
 
@@ -54,7 +56,6 @@ N_SLOTS, MAX_LEN, PREFILL_CHUNK, DECODE_BURST = 4, 1024, 64, 4
 # 256-row query chunk in whole chunks, so lengths past 256 are multiples
 PROMPT_LENS = (64, 512, 128, 256, 512, 64, 256, 128)
 GEN_LEN = 32
-KERNEL_REQUESTS, KERNEL_TOKENS = 2, 8
 
 # Two bf16 paths that compute the same logits in another order (chunked
 # ragged prefill against one whole prefill, Pallas against XLA) may pick
@@ -333,20 +334,6 @@ def serve_phase(lm, merged, mesh, seed: int, gen_len: int = GEN_LEN,
                 compiles=guard.counts())
 
 
-def with_kernels(tree):
-    """The same tree with every INT-N linear routed through the Pallas
-    kernels."""
-    from repro.core.schemes import map_linears
-
-    def on(path, lp):
-        if lp.scheme != "intq":
-            return lp
-        return dataclasses.replace(
-            lp, policy=dataclasses.replace(lp.policy, use_kernel=True))
-
-    return map_linears(tree, on)
-
-
 def kernel_refs(seed: int, k: int, n: int, rank: int, bits: int = 4,
                 group_size: int = 32):
     """Each quantized kernel once against kernels/ref.py at one [k, n];
@@ -405,34 +392,26 @@ def kernel_refs(seed: int, k: int, n: int, rank: int, bits: int = 4,
     return {name: rel(got[name], want[name]) for name in got}
 
 
-def kernel_phase(lm, merged, serve, seed: int, n_requests=KERNEL_REQUESTS,
-                 n_tokens=KERNEL_TOKENS, **engine_kw):
+def kernel_phase(lm, merged, seed: int, **engine_kw):
     import jax.numpy as jnp
 
     from repro.serving import engine as engine_mod
 
-    kparams = with_kernels(merged)
-    eng = _engine(lm, kparams, **engine_kw)
-    chunk = engine_kw.get("prefill_chunk", PREFILL_CHUNK)
-    hlo = engine_mod._JIT_STEP.lower(
-        lm, kparams, eng.aux, eng.cache,
-        jnp.zeros((eng.n_slots, chunk), jnp.int32),
-        jnp.zeros((eng.n_slots,), jnp.int32)).compile().as_text()
+    eng = _engine(lm, merged, **engine_kw)
+    zi = jnp.zeros((eng.n_slots,), jnp.int32)
+    hlo = engine_mod._JIT_BURST.lower(
+        lm, merged, eng.aux, eng.cache, zi, zi, zi,
+        k_steps=1).compile().as_text()
     _require("tpu_custom_call" in hlo,
-             "the kernel step's HLO has no tpu_custom_call: the Pallas "
-             "kernels did not lower to Mosaic")
-    streams, _, _ = _serve_timed(eng, serve["prompts"][:n_requests],
-                                 n_tokens)
-    splits = [first_split(s, x[:n_tokens], m, f"request {i} kernel vs XLA")
-              for i, (s, x, m) in enumerate(zip(
-                  streams, serve["streams"], serve["margins"]))]
+             "the decode burst's HLO has no tpu_custom_call: the merged "
+             "INT-N linears did not take the Pallas GEMV")
     cfg = lm.cfg
     errs = kernel_refs(seed, cfg.d_model, cfg.d_ff, cfg.quant.default.rank,
                        cfg.quant.default.bits, cfg.quant.default.group_size)
     bad = {name: e for name, e in errs.items() if not e < KERNEL_RTOL}
     _require(not bad, f"kernels off their reference by more than "
                       f"{KERNEL_RTOL:.0e} of the output scale: {bad}")
-    return splits, errs
+    return errs
 
 
 def four_chip_phase(lm, seed: int, **train_kw):
@@ -513,12 +492,10 @@ def run_one_chip(seed: int):
           f"TTFT {min(serve['ttft']) * 1e3:.0f}-{max(serve['ttft']) * 1e3:.0f}"
           f" ms; compiles {serve['compiles']}", flush=True)
 
-    splits, errs = kernel_phase(lm, merged, serve, seed)
-    print(f"[kernel] ok {on}: step HLO holds tpu_custom_call; first "
-          f"{KERNEL_TOKENS} tokens of {KERNEL_REQUESTS} requests vs XLA "
-          f"splits {splits}; vs kernels/ref.py at {lm.cfg.d_model}->"
-          f"{lm.cfg.d_ff}: " + ", ".join(f"{k} {v:.1e}" for k, v in
-                                         errs.items()), flush=True)
+    errs = kernel_phase(lm, merged, seed)
+    print(f"[kernel] ok {on}: decode burst HLO holds tpu_custom_call; vs "
+          f"kernels/ref.py at {lm.cfg.d_model}->{lm.cfg.d_ff}: "
+          + ", ".join(f"{k} {v:.1e}" for k, v in errs.items()), flush=True)
     print(f"[memory] peak {_peak_gb(dev):.2f} GB {on}", flush=True)
 
 

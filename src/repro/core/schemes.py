@@ -20,8 +20,10 @@ compute strategy for ``y = x @ W_eff``:
 Each linear's params live in a :class:`LinearParams` container whose
 *static* fields carry the scheme tag and the resolved :class:`QuantPolicy`
 — so forward/merge/partition dispatch is tag-driven, never by sniffing
-dict keys, and kernel routing (``use_kernel`` -> Pallas ``qmatmul`` /
-``qalora_matmul``) lives inside the qalora/intq schemes only.
+dict keys, and kernel routing lives inside the quantized schemes only:
+``use_kernel`` selects the Pallas ``qalora_matmul`` for qalora (and the
+banked variant for qalora_slot); intq picks the decode GEMV from the
+backend and the row count alone (:func:`repro.kernels.intq_matmul`).
 
 Registering a new scheme is ~50 lines::
 
@@ -86,7 +88,7 @@ class QuantPolicy:
     group_size: int = 32
     rank: int = 16
     s: float = 2.0
-    use_kernel: bool = False  # route through the Pallas kernels
+    use_kernel: bool = False  # qalora/qalora_slot: route through Pallas
     dtype: Any = jnp.float32  # compute/adapter dtype
     scale_dtype: Any = jnp.float32  # quantization scale/zero storage dtype
 
@@ -623,7 +625,9 @@ class QALoRASlotScheme(LinearScheme):
 
 @register_scheme("intq")
 class IntQScheme(LinearScheme):
-    """Bare INT-N group-wise linear: merged QA-LoRA output or PTQ result."""
+    """Bare INT-N group-wise linear: merged QA-LoRA output or PTQ result.
+    On TPU its decode-shaped calls run the fused dequant-GEMV, every other
+    call ``x @ dequantize(q)``; ``use_kernel`` is not read."""
 
     def init(self, key, d_in, d_out, pol):
         w = jax.random.normal(key, (d_in, d_out), jnp.float32) / jnp.sqrt(d_in)
@@ -631,10 +635,8 @@ class IntQScheme(LinearScheme):
                                         scale_dtype=pol.scale_dtype)}
 
     def apply(self, data, x, pol):
-        if pol.use_kernel:
-            from repro.kernels import qmatmul
-            return qmatmul(x, data["q"])
-        return x @ quant_lib.dequantize(data["q"], x.dtype)
+        from repro.kernels import intq_matmul  # lazy: kernels optional
+        return intq_matmul(x, data["q"])
 
     def merge(self, data, pol):
         return "intq", data

@@ -17,8 +17,9 @@ oracle is ``repro.core.qalora.bank_adapter_delta``); CPU validation runs
 with ``interpret=True``.
 """
 
-from .ops import (qmatmul, qalora_matmul, qalora_slot_matmul,  # noqa: F401
+from .ops import (qmatmul, intq_matmul, qalora_matmul,  # noqa: F401
+                  qalora_slot_matmul,
                   flash_mha, pick_blocks, heuristic_blocks)
-from .qmatvec import GEMV_MAX_M  # noqa: F401
+from .qmatvec import GEMV_MAX_M, INTQ_GEMV_MAX_M  # noqa: F401
 from .ref import qmatmul_ref, qalora_matmul_ref  # noqa: F401
 from . import autotune  # noqa: F401

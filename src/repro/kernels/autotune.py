@@ -97,10 +97,11 @@ def record(key: str, blocks: Tuple[int, int, int]) -> None:
 
 
 # A TPU block's last two dims must be multiples of (8, 128) or span the
-# whole array dim.  Blocks over K cut four arrays: x [m, K] (lanes),
-# packed codes [K/cpb, N], scale/zero [K/g, N] and the adapter A [K/g, r]
-# (sublanes).  So a K block that does not span K is a multiple of 128, of
-# 8 * g and of 8 * cpb; an N block that does not span N, of 128.
+# whole array dim.  Blocks over K cut five arrays: x [m, K] and the GEMV's
+# code planes of x [cpb, m, K/cpb] (lanes), packed codes [K/cpb, N],
+# scale/zero [K/g, N] and the adapter A [K/g, r] (sublanes).  So a K block
+# that does not span K is a multiple of 128 * cpb, of 8 * g and of 8 * cpb;
+# an N block that does not span N, of 128.
 
 
 def _legal_sizes(dim: int, mult: int, cap: int) -> List[int]:
@@ -115,7 +116,8 @@ def _legal_sizes(dim: int, mult: int, cap: int) -> List[int]:
 def block_k_sizes(k: int, bits: int, group_size: int, cap: int) -> List[int]:
     from repro.core.quant import codes_per_byte
 
-    mult = math.lcm(128, 8 * group_size, 8 * codes_per_byte(bits))
+    cpb = codes_per_byte(bits)
+    mult = math.lcm(128 * cpb, 8 * group_size, 8 * cpb)
     return _legal_sizes(k, mult, cap)
 
 

@@ -11,6 +11,11 @@ Dispatch: after flattening the leading dims, calls with M <=
 ``GEMV_MAX_M`` (= 8) route to :mod:`repro.kernels.qmatvec`, whose grid
 runs over (N, K) only — no M tiling, no padding a single decode token up
 to an MXU block.  Larger M takes the (M, N, K)-tiled matmul.
+
+The merged intq linear has its own route, :func:`intq_matmul`, chosen from
+the backend and the row count alone: on TPU, up to ``INTQ_GEMV_MAX_M``
+rows run the GEMV with :func:`decode_blocks`; everything else, and every
+call off TPU, runs ``x @ dequantize(q)`` in XLA.
 """
 
 from __future__ import annotations
@@ -21,18 +26,24 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.core.quant import QuantizedLinear
+from repro.core.quant import QuantizedLinear, dequantize
 from repro.core.qalora import QALoRAParams
 
 from . import autotune
 from .qmatmul import qmatmul_pallas
 from .qalora_fused import qalora_matmul_pallas
-from .qmatvec import (GEMV_MAX_M, qmatvec_pallas, qalora_matvec_pallas,
-                      qalora_slot_matvec_pallas)
+from .qmatvec import (GEMV_MAX_M, INTQ_GEMV_MAX_M, qmatvec_pallas,
+                      qalora_matvec_pallas, qalora_slot_matvec_pallas)
 
 
 def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _on_tpu() -> bool:
+    """The intq route's backend probe, apart from the interpret default so
+    a CPU test can take the TPU path with the kernel in interpret mode."""
+    return jax.default_backend() == "tpu"
 
 
 def heuristic_blocks(m: int, k: int, n: int, bits: int, group_size: int,
@@ -44,6 +55,56 @@ def heuristic_blocks(m: int, k: int, n: int, bits: int, group_size: int,
     block_m = min(128, m)
     # x + unpacked w + acc must fit VMEM comfortably (<2MB at defaults)
     return block_m, block_n, block_k
+
+
+# Decode GEMV blocks (merged intq route): Pallas pays a fixed cost per
+# grid step, so few, deep steps whose VMEM working set stays at half of
+# v5e's 16 MiB default scoped limit.  Blocks past 512 columns gained
+# nothing on the chip, and full-depth K blocks lost (PERF.md).
+DECODE_BLOCK_K, DECODE_BLOCK_N = 2048, 512
+DECODE_VMEM_BYTES = 8 * 2**20
+
+
+def gemv_vmem_bytes(m: int, block_k: int, block_n: int, bits: int,
+                    group_size: int) -> int:
+    """VMEM one GEMV grid step holds: double-buffered bf16 x planes,
+    packed codes, bf16 scale and zero and the output, the f32 accumulator,
+    and the int32 codes plus the f32 and bf16 weights of one code plane."""
+    from repro.core.quant import codes_per_byte
+
+    plane = block_k // codes_per_byte(bits) * block_n
+    inputs = (2 * m * block_k + plane + 2 * 2 * block_k // group_size * block_n
+              + 2 * m * block_n)
+    return 2 * inputs + 4 * m * block_n + (4 + 4 + 2) * plane
+
+
+def decode_blocks(m: int, k: int, n: int, bits: int,
+                  group_size: int) -> tuple:
+    """(block_n, block_k) of the decode GEMV: the widest legal N block up
+    to DECODE_BLOCK_N, then the deepest legal K block up to DECODE_BLOCK_K
+    whose step fits DECODE_VMEM_BYTES."""
+    bn = autotune.block_n_sizes(n, DECODE_BLOCK_N)[0]
+    bks = autotune.block_k_sizes(k, bits, group_size, DECODE_BLOCK_K)
+    fits = [bk for bk in bks if gemv_vmem_bytes(m, bk, bn, bits, group_size)
+            <= DECODE_VMEM_BYTES]
+    return bn, (fits or bks[-1:])[0]
+
+
+def intq_matmul(x, qt: QuantizedLinear):
+    """y = x @ dequant(qt) for a merged INT-N linear, any leading dims.
+    On TPU, decode-shaped calls (flattened rows <= INTQ_GEMV_MAX_M) run
+    the fused dequant-GEMV; every other call dequantizes in XLA."""
+    *lead, k = x.shape
+    m = math.prod(lead)
+    if not (_on_tpu() and m <= INTQ_GEMV_MAX_M):
+        return x @ dequantize(qt, x.dtype)
+    n = qt.d_out
+    bn, bk = decode_blocks(m, k, n, qt.bits, qt.group_size)
+    y = qmatvec_pallas(
+        x.reshape(m, k), qt.qweight, qt.scale, qt.zero, bits=qt.bits,
+        group_size=qt.group_size, block_n=bn, block_k=bk,
+        interpret=_default_interpret())
+    return y.reshape(*lead, n)
 
 
 def pick_blocks(m: int, k: int, n: int, bits: int, group_size: int,

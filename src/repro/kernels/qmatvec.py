@@ -8,6 +8,9 @@ activation strip [m, bk] rides in VMEM, the grid runs over (N, K) only,
 and the [bk, bn] dequantized weight tile is contracted against all m rows
 at once: the kernel stays bandwidth-bound on the packed INT-N weight
 stream, which is the QA-LoRA deployment win (paper Sec. 3.2 / App. B).
+The plain GEMV (`qmatvec_pallas`) dequantizes one code plane of the packed
+bytes at a time and contracts it with the matching plane of x, split
+outside the kernel, so the codes are never shuffled back into row order.
 
 A fused QA-LoRA variant (`qalora_matvec_pallas`) carries the group-pooled
 rank-r adapter epilogue in a second tiny VMEM scratch, mirroring
@@ -40,25 +43,44 @@ from repro.core.quant import codes_per_byte
 from .qmatmul import _dequant_block, group_pool
 
 # Above this M the padded-matmul path wins (MXU utilization catches up);
-# below it the GEMV grid avoids the pad-to-block_m waste entirely.
+# below it the GEMV grid avoids the pad-to-block_m waste entirely.  The
+# fused QA-LoRA variants keep this bound (the banked one unrolls a loop
+# over the rows).
 GEMV_MAX_M = 8
+# The merged intq route's own bound (`repro.kernels.ops.intq_matmul`): on
+# TPU, flattened row counts up to here run the plain GEMV.  Decode bursts
+# (one row per slot: 16 or 32) sit under it and ragged prefill steps (4096
+# rows) far above; the crossover against XLA's dequantize-then-dot is in
+# PERF.md.
+INTQ_GEMV_MAX_M = 128
 
 
 def _qmatvec_kernel(x_ref, qw_ref, scale_ref, zero_ref, o_ref, acc_ref, *,
                     bits: int, group_size: int, n_k: int):
+    """One [bk, bn] tile, code plane by code plane: plane t of the packed
+    bytes holds input rows ``r * cpb + t`` (:func:`repro.core.quant.pack`)
+    and meets x's plane t (``x[:, t::cpb]``, split outside the kernel),
+    so the codes are never interleaved back into row order."""
     k = pl.program_id(1)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    bk = x_ref.shape[-1]
-    w = _dequant_block(qw_ref[...], scale_ref[...], zero_ref[...],
-                       bits, bk, group_size, dtype=x_ref.dtype)
-    acc_ref[...] += jax.lax.dot_general(
-        x_ref[...], w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    cpb = codes_per_byte(bits)
+    q = qw_ref[...].astype(jnp.int32)
+    rows, bn = q.shape
+    per_group = group_size // cpb  # plane rows a scale row covers
+    scale = scale_ref[...].astype(jnp.float32)[:, None, :]
+    zero = zero_ref[...].astype(jnp.float32)[:, None, :]
+    mask = jnp.int32(2**bits - 1)
+    for t in range(cpb):
+        codes = (q >> (bits * t)) & mask if cpb > 1 else q
+        w = codes.astype(jnp.float32).reshape(rows // per_group, per_group, bn)
+        w = (w * scale + zero).reshape(rows, bn).astype(x_ref.dtype)
+        acc_ref[...] += jax.lax.dot_general(
+            x_ref[t], w, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(k == n_k - 1)
     def _done():
@@ -69,16 +91,18 @@ def qmatvec_pallas(x, qweight, scale, zero, *, bits: int, group_size: int,
                    block_n: int, block_k: int,
                    out_dtype=None, interpret: bool = False):
     """Raw pallas_call; use :mod:`repro.kernels.ops` for the dispatching
-    wrapper.  ``x: [m, K]`` with m <= GEMV_MAX_M (no M tiling)."""
+    wrapper.  ``x: [m, K]`` with m <= INTQ_GEMV_MAX_M (no M tiling)."""
     m, k_dim = x.shape
     n = qweight.shape[1]
-    assert m <= GEMV_MAX_M, (m, GEMV_MAX_M)
+    assert m <= INTQ_GEMV_MAX_M, (m, INTQ_GEMV_MAX_M)
     cpb = codes_per_byte(bits)
     assert k_dim % block_k == 0 and n % block_n == 0, (k_dim, n, block_k, block_n)
-    assert block_k % group_size == 0 and block_k % cpb == 0, (block_k, group_size, cpb)
+    assert block_k % group_size == 0 and group_size % cpb == 0, (block_k, group_size, cpb)
     n_k = k_dim // block_k
     grid = (n // block_n, n_k)
     out_dtype = out_dtype or x.dtype
+    # [cpb, m, K / cpb]: plane t holds x[:, t::cpb]
+    planes = x.reshape(m, k_dim // cpb, cpb).transpose(2, 0, 1)
 
     kernel = functools.partial(
         _qmatvec_kernel, bits=bits, group_size=group_size, n_k=n_k)
@@ -87,7 +111,7 @@ def qmatvec_pallas(x, qweight, scale, zero, *, bits: int, group_size: int,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((m, block_k), lambda j, k: (0, k)),
+            pl.BlockSpec((cpb, m, block_k // cpb), lambda j, k: (0, 0, k)),
             pl.BlockSpec((block_k // cpb, block_n), lambda j, k: (k, j)),
             pl.BlockSpec((block_k // group_size, block_n), lambda j, k: (k, j)),
             pl.BlockSpec((block_k // group_size, block_n), lambda j, k: (k, j)),
@@ -96,7 +120,7 @@ def qmatvec_pallas(x, qweight, scale, zero, *, bits: int, group_size: int,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((m, block_n), jnp.float32)],
         interpret=interpret,
-    )(x, qweight, scale, zero)
+    )(planes, qweight, scale, zero)
 
 
 def _qalora_matvec_kernel(x_ref, qw_ref, scale_ref, zero_ref, a_ref, b_ref,

@@ -89,6 +89,28 @@ def test_kernel_compiles_for_v5e(one_chip, no_compile_cache, kernel, k, n):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# (m, d_in, d_out): a starcoder2-7b decode burst's MLP up projection and
+# an h2o-danube-1.8b one's gate/up projection, at both burst widths
+GEMV_CASES = [(m, k, n) for m in (16, 32)
+              for k, n in ((4608, 18432), (2560, 6912))]
+
+
+@pytest.mark.parametrize("m,k,n", GEMV_CASES,
+                         ids=[f"m{m}-{k}x{n}" for m, k, n in GEMV_CASES])
+def test_decode_gemv_compiles_for_v5e(one_chip, no_compile_cache, m, k, n):
+    """The merged intq route's GEMV at decode-burst rows, with the decode
+    block rule's blocks."""
+    from repro.kernels.ops import decode_blocks
+    from repro.kernels.qmatvec import qmatvec_pallas
+    bn, bk = decode_blocks(m, k, n, BITS, GROUP)
+    qt = _qt(one_chip, k, n)
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
+    compiled = jax.jit(lambda x, q: qmatvec_pallas(
+        x, q.qweight, q.scale, q.zero, bits=BITS, group_size=GROUP,
+        block_n=bn, block_k=bk, interpret=False)).lower(x, qt).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_step_ragged_fits_one_v5e(one_chip, no_compile_cache):
     """One full-width ragged serving step of the merged INT4 model (4
     slots x 1024 positions, 64-token chunks, f32 cache) fits the chip."""

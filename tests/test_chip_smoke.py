@@ -38,8 +38,9 @@ def test_first_split_accepts_only_near_ties(cs):
 
 
 def test_kernel_phase_refuses_a_step_without_mosaic(cs):
-    """On the CPU the kernels run in interpret mode, so the compiled step
-    holds no tpu_custom_call and the phase must fail before serving."""
+    """On the CPU the merged INT-N linears never take the Pallas GEMV, so
+    the compiled decode burst holds no tpu_custom_call and the phase must
+    fail before checking the kernels."""
     import repro.configs as C
     from repro.core.schemes import merge_tree
     from repro.models.lm import LM
@@ -47,5 +48,5 @@ def test_kernel_phase_refuses_a_step_without_mosaic(cs):
     lm = LM(C.reduced("starcoder2-7b"))
     merged = merge_tree(lm.init(jax.random.PRNGKey(0)))
     with pytest.raises(cs.PhaseFailed, match="tpu_custom_call"):
-        cs.kernel_phase(lm, merged, {}, 0, max_len=16, prefill_chunk=4,
+        cs.kernel_phase(lm, merged, 0, max_len=16, prefill_chunk=4,
                         decode_burst=1)

@@ -68,8 +68,17 @@ def test_kernel_leading_dims():
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-4, atol=2e-4)
 
 
+# (m, d_in, d_out) of every merged INT4 linear the served cells run:
+# starcoder2-7b's decode bursts (16 rows) and h2o-danube-1.8b's (32 rows)
+SERVED_LINEARS = (
+    [(16, k, n) for k in (4608, 18432) for n in (4608, 512, 18432, 49152)]
+    + [(32, k, n) for k in (2560, 6912) for n in (2560, 640, 6912, 32000)])
+
+
 def test_block_picker_constraints():
     from repro.kernels import pick_blocks
+    from repro.kernels.ops import (DECODE_VMEM_BYTES, decode_blocks,
+                                   gemv_vmem_bytes)
     from repro.core.quant import codes_per_byte
     for bits in BITS:
         for k in (64, 512, 22016):
@@ -77,6 +86,15 @@ def test_block_picker_constraints():
                 bm, bn, bk = pick_blocks(128, k, n, bits, 32)
                 assert k % bk == 0 and n % bn == 0
                 assert bk % 32 == 0 and bk % codes_per_byte(bits) == 0
+    # the decode GEMV's blocks: legal TPU tiles (x's code planes are
+    # [cpb, m, bk/cpb], codes [bk/cpb, bn], scale/zero [bk/g, bn]) within
+    # the VMEM budget, at every linear shape of both served models
+    for m, k, n in SERVED_LINEARS:
+        bn, bk = decode_blocks(m, k, n, 4, 32)
+        assert k % bk == 0 and n % bn == 0 and bk % 32 == 0
+        assert (bk // 2) % 128 == 0 and (bk // 32) % 8 == 0
+        assert bn % 128 == 0
+        assert gemv_vmem_bytes(m, bk, bn, 4, 32) <= DECODE_VMEM_BYTES
 
 
 # ---------------------------------------------------------------------------
@@ -85,20 +103,58 @@ def test_block_picker_constraints():
 
 
 @pytest.mark.parametrize("bits", [2, 3, 4])
-@pytest.mark.parametrize("m", [1, 2, 8])
+@pytest.mark.parametrize("m", [1, 2, 8, 16, 32])
 def test_qmatvec_matches_qmatmul_and_ref(bits, m):
-    """GEMV path == matmul kernel == dense reference at decode M."""
+    """GEMV path == matmul kernel == dense reference at decode M: through
+    ``qmatmul`` up to GEMV_MAX_M, and at the merged intq route's decode
+    bursts (16 and 32 rows) through ``qmatvec_pallas`` itself."""
+    from repro.kernels import GEMV_MAX_M
     from repro.kernels.qmatmul import qmatmul_pallas
+    from repro.kernels.qmatvec import qmatvec_pallas
 
     k, n, g = 128, 96, 32
     x, qt, _ = _setup(bits, m, k, n, g, jnp.float32)
-    y = qmatmul(x, qt, interpret=True)  # dispatches to qmatvec (m <= 8)
+    if m <= GEMV_MAX_M:
+        y = qmatmul(x, qt, interpret=True)  # dispatches to qmatvec
+    else:
+        y = qmatvec_pallas(x, qt.qweight, qt.scale, qt.zero, bits=bits,
+                           group_size=g, block_n=48, block_k=64,
+                           interpret=True)
     yr = qmatmul_ref(x, qt)
     ym = qmatmul_pallas(x, qt.qweight, qt.scale, qt.zero, bits=bits,
                         group_size=g, block_m=m, block_n=48, block_k=64,
                         interpret=True)
     np.testing.assert_allclose(np.asarray(y), np.asarray(yr), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(y), np.asarray(ym), rtol=2e-4, atol=2e-4)
+
+
+def test_qmatvec_kernel_never_interleaves_codes():
+    """The GEMV dequantizes each code plane where it lies: its kernel body
+    stacks no planes back into row order.  The interleaving body ran 4-5x
+    slower on a v5e and, loaded from the persistent compile cache, stalled
+    the first decode burst for seconds (PERF.md)."""
+    from repro.kernels.qmatvec import qmatvec_pallas
+
+    x, qt, _ = _setup(4, 16, 512, 256, 32, jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda x: qmatvec_pallas(
+        x, qt.qweight, qt.scale, qt.zero, bits=4, group_size=32,
+        block_n=128, block_k=256, interpret=False))(x)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    body = str(call.params["jaxpr"])
+    assert "shift_right" in body  # the planes are cut from the bytes
+    assert "concatenate" not in body
+
+
+def test_qmatvec_refuses_rows_past_the_intq_bound():
+    """The GEMV holds all rows in one block: past the bound the merged
+    intq route uses, it refuses instead of tiling."""
+    from repro.kernels import INTQ_GEMV_MAX_M
+    from repro.kernels.qmatvec import qmatvec_pallas
+
+    x, qt, _ = _setup(4, INTQ_GEMV_MAX_M + 1, 128, 96, 32, jnp.float32)
+    with pytest.raises(AssertionError):
+        qmatvec_pallas(x, qt.qweight, qt.scale, qt.zero, bits=4,
+                       group_size=32, block_n=48, block_k=64, interpret=True)
 
 
 @pytest.mark.parametrize("bits", [2, 3, 4])

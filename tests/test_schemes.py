@@ -429,3 +429,34 @@ def test_serve_driver_mixed_policy_cli():
     main(["--arch", "gemma3-1b", "--reduced", "--requests", "2",
           "--prompt-len", "4", "--gen-len", "2", "--verify",
           "--policy", "*=int4,*/attn/wo=int8,lm_head=fp"])
+
+
+# ---------------------------------------------------------------------------
+# 6. the merged intq linear picks its path from the backend and row count
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("backend", ["tpu", "cpu"])
+@pytest.mark.parametrize("rows", [1, 16, 32, 4096])
+def test_intq_route_by_backend_and_rows(monkeypatch, backend, rows):
+    """On TPU, decode-shaped rows run the fused dequant-GEMV (a
+    pallas_call; interpret mode here) and 4096-row prefill steps stay on
+    ``x @ dequantize(q)``; off TPU nothing takes the kernel.  Both paths
+    give the dequantize-then-dot result, and ``use_kernel`` is not read."""
+    from repro.kernels import INTQ_GEMV_MAX_M, ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: backend == "tpu")
+    d_in, d_out = 128, 256
+    pol = QuantPolicy(mode="intq", bits=4, group_size=32, use_kernel=False)
+    p = S.linear_init(jax.random.PRNGKey(1), d_in, d_out, pol)
+    lead = (rows // 128, 128) if rows > 128 else (rows, 1)
+    x = jax.random.normal(jax.random.PRNGKey(2), (*lead, d_in))
+
+    jaxpr = str(jax.make_jaxpr(lambda x: S.linear_apply(p, x))(x))
+    want_kernel = backend == "tpu" and rows <= INTQ_GEMV_MAX_M
+    assert ("pallas_call" in jaxpr) == want_kernel
+    assert rows in (1, 16, 32) or not want_kernel  # prefill stays on XLA
+    y = S.linear_apply(p, x)
+    want = x @ quant_lib.dequantize(p["q"], x.dtype)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want),
+                               rtol=2e-4, atol=2e-4)
