@@ -3,13 +3,34 @@
 Everything that belongs to one configuration, traffic mix, cell or
 per-layer metric lives in a file of its own, found by its name:
 
-  configs/<config>.json     sizes and quantization of one model
+  configs/<config>.json     sizes and quantization of one model, and the
+                            names of its family and reference
   configs/<reference>.py    the plain reference a configuration names
+  families/<family>.py      what is particular to one model family:
+      PROGRAM_KEYS                        the config's keys the program's
+                                          ArchConfig takes over
+      raw_weights(cfg, key) -> raw        every array the reference reads,
+                                          drawn from one key (jittable)
+      fill_program(tree, cfg, raw) -> tree
+                                          raw in the program's tree, whose
+                                          shapes come from lm.init
+      adapters(raw) -> {name: {"a", "b"}} the trainable QA-LoRA pairs
+      serve_pass(cfg, rows) -> (flops, bytes)
+                                          one forward pass's required work;
+                                          rows: [(cache length, rows
+                                          consumed)] per slot
+      train_step(cfg, batch, seq) -> (flops, bytes)
+                                          one QA-LoRA step's required work
+      linear_serve_pass(cfg, rows), linear_train_step(cfg, batch, seq)
+                                          the same, of the ``linear`` scope
+      TINY, TINY_CONTROL                  CPU stand-in sizes for the cell
+                                          tests and the control test
   traffic/<traffic>.json    parameters of one traffic mix
   cells/<workload>.json     engine settings and correctness limits
   metrics/<metric>.py       the reader of one per-layer metric
 
-so a later cell, mix or metric is a new file plus its manifest entries.
+so a later family is a new file plus a configuration that names it, and a
+later cell, mix or metric is new files plus their manifest entries.
 """
 
 from __future__ import annotations
@@ -121,8 +142,8 @@ def validate(man: dict) -> dict:
     return man
 
 
-def load(path: str = MANIFEST) -> dict:
-    with open(path) as f:
+def load() -> dict:
+    with open(MANIFEST) as f:
         return validate(json.load(f))
 
 
@@ -158,8 +179,9 @@ _MODULES: dict = {}
 
 
 def load_module(path: str, name: str):
-    """Import one file by path, once (metric readers and references carry
-    dots in their names, so they are not importable as modules)."""
+    """Import one file by path, once (metric readers, families and
+    references are found by name, and some names carry dots, so they are
+    not importable as modules)."""
     if path not in _MODULES:
         spec = importlib.util.spec_from_file_location(name, path)
         mod = importlib.util.module_from_spec(spec)
@@ -171,6 +193,12 @@ def load_module(path: str, name: str):
 def metric_reader(name: str):
     return load_module(os.path.join(CHIP_DIR, "metrics", name + ".py"),
                        "metric_" + name.replace(".", "_").replace("-", "_"))
+
+
+def family(config: dict):
+    return load_module(os.path.join(CHIP_DIR, "families",
+                                    config["family"] + ".py"),
+                       "family_" + config["family"].replace("-", "_"))
 
 
 def reference(config: dict):

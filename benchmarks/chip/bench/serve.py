@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import manifest as M
 from . import spans as S
 from . import traffic as T
 from . import weights as W
@@ -295,11 +296,12 @@ def layer_context(run: dict, server: Server, peak: dict) -> dict:
     cfg, chunk = server.cfg, server.eng["prefill_chunk"]
     t_open, t_close = run["t_open"], run["t_close"]
     s0, s1 = run["stats0"], run["stats1"]
+    fam = M.family(cfg)
     span_s, n_pass, least = 0.0, 0, 0.0
     for step, ps in passes(run, chunk):
         span_s += step.t1 - step.t0
         n_pass += len(ps)
-        least += sum(work.least_seconds(*work.serve_pass(cfg, rows), peak)
+        least += sum(work.least_seconds(*fam.serve_pass(cfg, rows), peak)
                      for rows in ps)
     return {
         "slot_steps": s1.slot_steps - s0.slot_steps,

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import manifest as M
 from . import spans as S
 from . import traffic as T
 from . import weights as W
@@ -132,8 +133,8 @@ def end_to_end(run: dict, trainer: Trainer) -> dict:
 
 
 def layer_context(run: dict, trainer: Trainer, peak: dict) -> dict:
-    f, b = work.train_step(trainer.cfg, trainer.mix["batch"],
-                           trainer.mix["seq"])
+    f, b = M.family(trainer.cfg).train_step(
+        trainer.cfg, trainer.mix["batch"], trainer.mix["seq"])
     window = run["t_close"] - run["t_open"]
     return {"window_s": window,
             "least_s": len(run["losses"]) * work.least_seconds(f, b, peak)}
@@ -152,7 +153,7 @@ def reference_steps(ref, cfg: dict, mix: dict, cell: dict, seed: int,
     adapters stored in their bf16 after every update."""
     opt = cell["optimizer"]
     raw = W.make_raw(cfg, seed)
-    ad = {n: {"a": p["a"], "b": p["b"]} for n, p in raw["lin"].items()}
+    ad = M.family(cfg).adapters(raw)
     p0 = ad
     mu = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), ad)
     nu = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32), ad)

@@ -17,9 +17,9 @@ One JSON line on standard output:
   compiles         executables the engine's jits (the train step's jit)
                    added in the window; trace_compiles: compile events
                    the trace holds inside the window
-  linear_roofline  least time of the window's linear work
-                   (bench/linear_work.py) over the device seconds under
-                   the ``linear`` scope, in %
+  linear_roofline  least time of the window's linear work (the family's
+                   linear_serve_pass, linear_train_step) over the device
+                   seconds under the ``linear`` scope, in %
   device_scopes    busy seconds by scope (bench/scopes.py), ``other`` the
                    rest; unmapped_s: device seconds of operations found
                    in no compiled program
@@ -76,7 +76,7 @@ def serve_programs(engine) -> dict:
 
 
 def serve_readings(c, seed, seconds, peak, tracer):
-    from bench import linear_work as LW, serve as SV, traffic as T, work
+    from bench import manifest as M, serve as SV, traffic as T, work
 
     prog = SV.Server(c, seed)
     prog.warm_up()
@@ -88,7 +88,9 @@ def serve_readings(c, seed, seconds, peak, tracer):
     t_open, t_close = run["t_open"], run["t_close"]
     pre = [r.ticket.t_first - r.ticket.t_admit for r in run["recs"]
            if t_open <= r.due < t_close and r.ticket.t_first is not None]
-    least = sum(work.least_seconds(*LW.serve_pass(prog.cfg, rows), peak)
+    fam = M.family(prog.cfg)
+    least = sum(work.least_seconds(*fam.linear_serve_pass(prog.cfg, rows),
+                                   peak)
                 for _, ps in SV.passes(run, prog.eng["prefill_chunk"])
                 for rows in ps)
     texts = serve_programs(prog.fe.engine)
@@ -104,7 +106,7 @@ def train_readings(c, seed, seconds, peak, tracer):
     import jax
     import jax.numpy as jnp
 
-    from bench import linear_work as LW, train as TN, work
+    from bench import manifest as M, train as TN, work
 
     prog = TN.Trainer(c, seed)
     prog.first_steps()
@@ -113,7 +115,7 @@ def train_readings(c, seed, seconds, peak, tracer):
     compiles = prog.step_fn._cache_size() - n0
     b, s = prog.mix["batch"], prog.mix["seq"]
     least = len(run["losses"]) * work.least_seconds(
-        *LW.train_step(prog.cfg, b, s), peak)
+        *M.family(prog.cfg).linear_train_step(prog.cfg, b, s), peak)
     shape = jnp.zeros((b, s), jnp.int32)
     with jax.set_mesh(prog.mesh):
         texts = _texts([prog.step_fn.lower(

@@ -1,10 +1,10 @@
 """Every cell of BENCHMARK.json, run on the CPU at a tiny stand-in of its
-configuration, untraced and traced: the last line is one JSON object with
-the result's keys, ``metrics`` holds every metric the cell declares, and
-the run is correct.  With the timed path broken underneath (a served
-token altered where it is produced; a train step that returns its state
-unchanged; a train step that drops half of its batch) the same run comes
-out not correct."""
+configuration (its family's ``TINY``), untraced and traced: the last line
+is one JSON object with the result's keys, ``metrics`` holds every metric
+the cell declares, and the run is correct.  With the timed path broken
+underneath (a served token altered where it is produced; a train step
+that returns its state unchanged; a train step that drops half of its
+batch) the same run comes out not correct."""
 
 import json
 import os
@@ -26,12 +26,6 @@ MAN = M.load()
 CELLS = [w["name"] for w in MAN["workloads"]]
 SEED = 2**33 + 12345  # past 32 bits, as the benchmark's seeds may be
 
-TINY = {"n_layers": 2, "d_model": 128, "n_heads": 4, "n_kv_heads": 2,
-        "head_dim": 32, "d_ff": 256, "vocab": 512,
-        "quant": {"bits": 4, "group_size": 32, "rank": 8, "s": 2.0,
-                  "scale_dtype": "bfloat16"},
-        "init": {"embed_std": 0.02, "head_std": 0.1, "norm_jitter": 0.1,
-                 "adapter_b_std": 0.01}}
 SERVE = {"clients": 4, "rate": 20.0, "lead_s": 0.3, "drain_s": 5,
          "prompt": {"median": 12, "sigma": 0.6, "min": 4, "max": 40},
          "output": {"median": 10, "sigma": 0.6, "min": 2, "max": 24}}
@@ -41,20 +35,22 @@ TRAIN = {"batch": 2, "seq": 64,
          "doc": {"median": 20, "sigma": 1.0, "min": 4, "max": 100}}
 
 
-def stand_in(workload: str) -> dict:
-    c = M.cell(MAN, workload)
+def stand_in(workload: str, man: dict = MAN) -> dict:
+    c = M.cell(man, workload)
+    tiny = M.family(c["config"]).TINY
     if c["traffic"]["kind"] == "train":
-        return {"config": TINY, "traffic": TRAIN}
-    return {"config": TINY, "traffic": SERVE,
+        return {"config": tiny, "traffic": TRAIN}
+    return {"config": tiny, "traffic": SERVE,
             "cell": {"engine": ENGINE,
                      "limits": {**c["cell"]["limits"], "checked_tokens": 16,
                                 "max_requests": 4}}}
 
 
-def run(workload: str, trace: bool, capsys) -> dict:
+def run(workload: str, trace: bool, capsys, man: dict = MAN) -> dict:
     run_py = M.load_module(os.path.join(CHIP, "run.py"), "chipbench_run")
     result = run_cell(workload, SEED, 1.0, trace, 0.0, allow_cpu=True,
-                      stand_in=stand_in(workload), peak_kind="TPU v5 lite")
+                      stand_in=stand_in(workload, man),
+                      peak_kind="TPU v5 lite")
     capsys.readouterr()
     run_py.emit(result)
     out, err = capsys.readouterr()
@@ -63,20 +59,20 @@ def run(workload: str, trace: bool, capsys) -> dict:
     return last
 
 
-@pytest.mark.parametrize("trace", [False, True], ids=["untraced", "traced"])
-@pytest.mark.parametrize("workload", CELLS)
-def test_cell_prints_every_declared_metric(workload, trace, capsys):
-    last = run(workload, trace, capsys)
+def assert_complete_and_correct(last: dict, workload: str, trace: bool,
+                                man: dict = MAN):
+    """``last`` holds every key and every metric ``workload`` declares,
+    each in its unit and range, and is correct."""
     assert {"correct", "attempted", "failed", "metrics", "device"} <= set(last)
     assert list(last)[-1] == "checks"
-    c = M.cell(MAN, workload)
+    c = M.cell(man, workload)
     declared = [m["name"] for m in c["end_to_end"]]
     if trace:
         declared += [m["name"] for m in c["per_layer"]]
         assert 0 < last["device"]["busy_s"] <= last["device"]["window_s"]
         assert set(last["breakdown"]) == {"device_ops", "idle_gaps"}
     assert sorted(last["metrics"]) == sorted(declared)
-    units = {m["name"]: m["unit"] for m in MAN["end_to_end"] + MAN["per_layer"]}
+    units = {m["name"]: m["unit"] for m in man["end_to_end"] + man["per_layer"]}
     for name, m in last["metrics"].items():
         assert m["unit"] == units[name]
         assert isinstance(m["value"], float) and m["value"] > 0, name
@@ -84,6 +80,12 @@ def test_cell_prints_every_declared_metric(workload, trace, capsys):
             assert m["value"] <= 100, name
     assert last["attempted"] > 0 and last["failed"] == 0
     assert last["correct"] is True, last["checks"]
+
+
+@pytest.mark.parametrize("trace", [False, True], ids=["untraced", "traced"])
+@pytest.mark.parametrize("workload", CELLS)
+def test_cell_prints_every_declared_metric(workload, trace, capsys):
+    assert_complete_and_correct(run(workload, trace, capsys), workload, trace)
 
 
 SERVING = [w for w in CELLS if M.cell(MAN, w)["traffic"]["kind"] != "train"]
@@ -94,7 +96,8 @@ TRAINING = [w for w in CELLS if M.cell(MAN, w)["traffic"]["kind"] == "train"]
 def test_altered_token_is_not_correct(workload, capsys, monkeypatch):
     from repro.serving.scheduler import Scheduler
 
-    for name, fn in faults.altered_commits(Scheduler, TINY["vocab"]).items():
+    vocab = stand_in(workload)["config"]["vocab"]
+    for name, fn in faults.altered_commits(Scheduler, vocab).items():
         monkeypatch.setattr(Scheduler, name, fn)
     last = run(workload, False, capsys)
     assert last["correct"] is False

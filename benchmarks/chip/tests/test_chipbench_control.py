@@ -22,21 +22,13 @@ from test_chipbench_cells import CELLS, MAN, stand_in  # noqa: E402
 
 STUDY = M.load_module(os.path.join(CHIP, "study.py"), "chipbench_study")
 SEEDS = (2**31 + 11, 2**31 + 12, 2**31 + 13)
-# a larger stand-in than the cell test's, so that a run serves enough
-# tokens for near-ties, where a lower precision flips the top token
-WIDER = {"n_layers": 4, "d_model": 256, "n_heads": 8, "n_kv_heads": 2,
-         "head_dim": 32, "d_ff": 512, "vocab": 1024,
-         "quant": {"bits": 4, "group_size": 32, "rank": 8, "s": 2.0,
-                   "scale_dtype": "bfloat16"},
-         "init": {"embed_std": 0.02, "head_std": 0.1, "norm_jitter": 0.1,
-                  "adapter_b_std": 0.01}}
 
 
 def cell(workload):
     c = M.cell(MAN, workload)
     for part, over in stand_in(workload).items():
         c[part] = {**c[part], **over}
-    c["config"] = {**c["config"], **WIDER}
+    c["config"] = {**c["config"], **M.family(c["config"]).TINY_CONTROL}
     if c["traffic"]["kind"] != "train":
         c["cell"] = {**c["cell"], "limits": {**c["cell"]["limits"],
                                              "checked_tokens": 160,

@@ -1,8 +1,8 @@
-"""The scope reduction (bench/scopes.py) and the linear layer's work
-(bench/linear_work.py): device seconds per named scope read through the
-compiled programs' metadata, idle gaps named by the innermost span, on a
-hand-made trace with known answers and on the recorded v5e slice, where
-it agrees with trace.py; and layers.py end to end on the CPU stand-ins."""
+"""The scope reduction (bench/scopes.py): device seconds per named scope
+read through the compiled programs' metadata, idle gaps named by the
+innermost span, on a hand-made trace with known answers and on the
+recorded v5e slice, where it agrees with trace.py; and layers.py end to
+end on the CPU stand-ins."""
 
 import gzip
 import json
@@ -18,8 +18,8 @@ CHIP = os.path.dirname(HERE)
 sys.path.insert(0, CHIP)
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(CHIP)), "src"))
 
-from bench import linear_work as LW, manifest as M, scopes as SC  # noqa: E402
-from bench import spans as S, trace as T, work  # noqa: E402
+from bench import manifest as M, scopes as SC  # noqa: E402
+from bench import spans as S, trace as T  # noqa: E402
 from repro.runtime import tracing as R  # noqa: E402
 
 MS = 1_000_000  # ns
@@ -128,23 +128,6 @@ def test_recorded_v5e_trace_agrees_with_trace_py():
     # list does
     assert sum(r["idle_by_span"].values()) == pytest.approx(
         old["window_s"] - old["busy_s"], rel=1e-4)
-
-
-def test_linear_work_by_hand():
-    cfg = M.read_json(os.path.join(CHIP, "configs", "starcoder2-7b.json"))
-    d, v, L = 4608, 49152, 32
-    per_layer = d * d + 2 * d * 512 + d * d + 2 * d * 18432
-    assert work.linear_params(cfg) == L * per_layer
-    rows = [(100, 1), (0, 256), (50, 0)]
-    f, b = LW.serve_pass(cfg, rows)
-    assert f == 2 * 257 * L * per_layer + 2 * 2 * d * v
-    assert b == (work.quantized_bytes(cfg) + d * v * 2 + 257 * d * 2)
-    f, b = LW.train_step(cfg, 2, 2048)
-    ad = work.adapter_params(cfg)
-    assert f == 4096 * (4 * L * per_layer + 6 * ad)
-    assert b == work.quantized_bytes(cfg) + 2 * ad
-    # the linear layer is part of each step's required work
-    assert f < work.train_step(cfg, 2, 2048)[0]
 
 
 @pytest.mark.parametrize("workload",

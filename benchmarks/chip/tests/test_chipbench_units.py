@@ -1,5 +1,6 @@
-"""The benchmark's yardstick on its own: required work and peaks, the
-seeded traffic, the manifest loader and the per-layer readers."""
+"""The benchmark's yardstick on its own: peaks, the seeded traffic, the
+manifest loader and the per-layer readers (the required work is each
+family's: test_chipbench_families.py)."""
 
 import copy
 import math
@@ -18,56 +19,6 @@ from bench import manifest as M  # noqa: E402
 from bench import readers, traffic as T, work  # noqa: E402
 
 MAN = M.load()
-CONFIGS = {c["name"]: M.read_json(os.path.join(M.REPO, c["file"]))
-           for c in MAN["configs"]}
-
-# one layer's projections, counted by hand from the published widths
-LAYER_WEIGHTS = {
-    # wq, wo 4608x4608; wk, wv 4608x512; up, down 4608x18432
-    "starcoder2-7b": 2 * 4608 * 4608 + 2 * 4608 * 512 + 2 * 4608 * 18432,
-    # wq, wo 2560x2560; wk, wv 2560x640; gate, up, down 2560x6912
-    "h2o-danube-1.8b": 2 * 2560 * 2560 + 2 * 2560 * 640 + 3 * 2560 * 6912,
-}
-
-
-@pytest.mark.parametrize("name", sorted(LAYER_WEIGHTS))
-def test_one_layer_counted_by_hand(name):
-    cfg = CONFIGS[name]
-    n = LAYER_WEIGHTS[name]
-    assert work.linear_params(cfg) == cfg["n_layers"] * n
-    # INT4: half a byte a weight; a bf16 scale and zero per 32 input rows
-    assert work.quantized_bytes(cfg) == cfg["n_layers"] * (n // 2 + n * 4 // 32)
-    kv = cfg["n_layers"] * 2 * cfg["n_kv_heads"] * cfg["head_dim"] * 2
-    assert work.kv_bytes_per_token(cfg) == kv
-    assert work.head_bytes(cfg) == cfg["d_model"] * cfg["vocab"] * 2
-    one = cfg["n_layers"] * 4 * cfg["n_heads"] * cfg["head_dim"]
-    assert work.attention_flops(cfg, 0, 1) == one
-    assert work.attention_flops(cfg, 9, 1) == 10 * one
-
-
-def test_starcoder2_base_is_the_published_size():
-    # the INT4 base with its bf16 embedding and head: 5.25 GB
-    cfg = CONFIGS["starcoder2-7b"]
-    total = work.quantized_bytes(cfg) + 2 * work.head_bytes(cfg)
-    assert abs(total - 5.247e9) < 0.01e9
-
-
-def test_serve_pass_and_train_step_arithmetic():
-    cfg = CONFIGS["starcoder2-7b"]
-    f, b = work.serve_pass(cfg, [(100, 1), (7, 0)])
-    assert f == (2 * work.linear_params(cfg) + work.attention_flops(cfg, 100, 1)
-                 + 2 * cfg["d_model"] * cfg["vocab"])
-    assert b == (work.quantized_bytes(cfg) + work.head_bytes(cfg)
-                 + 101 * work.kv_bytes_per_token(cfg) + cfg["d_model"] * 2)
-    f2, _ = work.train_step(cfg, 2, 16)
-    tokens = 32
-    assert f2 == (tokens * (4 * work.linear_params(cfg)
-                            + 6 * work.adapter_params(cfg)
-                            + 4 * cfg["d_model"] * cfg["vocab"])
-                  + 2 * 3 * work.attention_flops(cfg, 0, 16))
-    # the window never binds danube below its 4096 positions
-    d = CONFIGS["h2o-danube-1.8b"]
-    assert work.attention_flops(d, 5000, 1) == work.attention_flops(d, 4095, 1)
 
 
 def test_peaks_by_device_kind():
@@ -139,6 +90,7 @@ def test_the_manifest_and_its_files():
         for m in c["per_layer"]:
             assert m["moves"] in reports
         M.reference(c["config"])
+        M.family(c["config"])
 
 
 def _bad(edit):
